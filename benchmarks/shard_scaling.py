@@ -133,6 +133,9 @@ def run_child(devices: int, arch: str, backend: str, doc_len: int,
     env["PYTHONPATH"] = (os.path.abspath(src) + os.pathsep
                          + env.get("PYTHONPATH", ""))
     env.pop("XLA_FLAGS", None)          # the child pins its own
+    # the child emulates the mesh on fake CPU devices; it must never
+    # ask for an accelerator the parent process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     code = CHILD % dict(devices=devices, arch=arch, backend=backend,
                         doc_len=doc_len, requests=requests,
                         max_new=max_new, pages=pages)

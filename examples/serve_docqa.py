@@ -101,7 +101,8 @@ print("undersized pool (preemption + chunked prefill) outputs: OK")
 # under shard_map over a (data, model) mesh.  In-process this demo gets
 # whatever devices exist (a 1x1 mesh on a plain run — the full sharded
 # code path, collectives degenerate); launch/serve.py --mesh DxM runs
-# real multi-device meshes via fake host devices.
+# over the devices present (on the CPU, set
+# XLA_FLAGS=--xla_force_host_platform_device_count=N to emulate N).
 from repro.distributed import decode_mesh  # noqa: E402
 
 mesh = decode_mesh(1, 1)

@@ -267,10 +267,11 @@ class ShardedKVPool(PagedKVPool):
         rows = D * self.allocator.stride
         self.sharding = jax.sharding.NamedSharding(
             mesh, paged_pool_spec(mesh, n_kv))
-        self.k = jax.device_put(
-            jnp.zeros((n_layers, rows, page_size, n_kv, head_dim), dtype),
-            self.sharding)
-        self.v = jax.device_put(jnp.zeros_like(self.k), self.sharding)
+        # allocated sharded: a pool sized for the whole mesh need not
+        # fit on its first device
+        shape = (n_layers, rows, page_size, n_kv, head_dim)
+        self.k = jnp.zeros(shape, dtype, device=self.sharding)
+        self.v = jnp.zeros(shape, dtype, device=self.sharding)
 
     @property
     def num_shards(self) -> int:

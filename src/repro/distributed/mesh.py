@@ -7,10 +7,10 @@ builders make *plain* meshes (no GSPMD auto axis types): the sharded
 decode step is traced manually under ``shard_map``, which owns both
 axes explicitly.
 
-Everything runs on CPU under
-``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (the pattern the
-launch tests use); a ``1x1`` mesh exercises the full SPMD code path on
-a single device.
+A mesh takes the devices present: TPU chips, or on the CPU host devices
+emulated with ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
+(the pattern the tests use); a ``1x1`` mesh exercises the full SPMD
+code path on a single device.
 """
 
 from __future__ import annotations
@@ -45,8 +45,9 @@ def decode_mesh(data: int = 1, model: int = 1):
     if len(devs) < n:
         raise ValueError(
             f"mesh {data}x{model} needs {n} devices, have {len(devs)} "
-            f"(set XLA_FLAGS=--xla_force_host_platform_device_count={n} "
-            f"before jax initialises)")
+            f"(on the CPU, emulate them with XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={n} set before jax "
+            f"initialises)")
     return jax.sharding.Mesh(
         np.array(devs[:n]).reshape(data, model), ("data", "model"))
 

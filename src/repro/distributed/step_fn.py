@@ -47,7 +47,6 @@ from typing import Any, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig
@@ -183,11 +182,17 @@ def make_sharded_step_fn(cfg: ModelConfig, backend,
                         l = l.at[si].set(lg, mode="drop")
                 o_flat = o.astype(q_loc.dtype).reshape(B, 1, hq_loc * hd)
                 if heads_sharded:
-                    # TP epilogue: partial output projection, psum(model)
+                    # TP epilogue: partial output projection, psum(model).
+                    # Partials are summed in f32 and rounded once, as the
+                    # single-device projection rounds its f32 accumulator
+                    # once, so token streams match at any mesh shape.
                     w_rows = jax.lax.dynamic_slice_in_dim(
                         p["attn"]["wo"]["w"], m_idx * hq_loc * hd,
                         hq_loc * hd, 0)
-                    y = jax.lax.psum(o_flat @ w_rows, "model")
+                    y = jax.lax.psum(
+                        jnp.matmul(o_flat, w_rows,
+                                   preferred_element_type=jnp.float32),
+                        "model").astype(o_flat.dtype)
                 else:
                     y = L.dense(p["attn"]["wo"], o_flat)
                 x = x + y
@@ -213,9 +218,9 @@ def make_sharded_step_fn(cfg: ModelConfig, backend,
     state_spec = StepState(pool_spec, pool_spec, P(), P())
     base_spec = ShardedStepBase(P(), P(), P("data"), P(), P(), P("data"),
                                 P(), P(), P())
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P(), state_spec, P(), P(), base_spec, P(), P("data")),
         out_specs=(P(), P(), state_spec),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(fn, donate_argnums=(1,))

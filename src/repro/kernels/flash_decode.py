@@ -16,7 +16,7 @@ baseline over dense tensors.  Both are exposed to the benchmarks.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +24,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import _CompilerParams
+from . import pallas_interpret
 
 MASK_VALUE = -1e30
 
@@ -93,7 +93,7 @@ def flash_decode(q: jnp.ndarray,        # (B, h_q, d)
                  v: jnp.ndarray,
                  kv_lens: jnp.ndarray,  # (B,) int32
                  *, chunk: int = 256, window: int = 0,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: Optional[bool] = None) -> jnp.ndarray:
     B, h_q, d = q.shape
     _, L, n_kv, _ = k.shape
     group = h_q // n_kv
@@ -121,8 +121,9 @@ def flash_decode(q: jnp.ndarray,        # (B, h_q, d)
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, h_q, d), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=(pallas_interpret() if interpret is None
+                   else interpret),
     )(kv_lens.astype(jnp.int32), q, k, v)
     return out.astype(q.dtype)

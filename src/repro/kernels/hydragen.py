@@ -143,7 +143,8 @@ def _prefix_phase(q, k_pool, v_pool, ha: HydragenArrays, window: int):
     vf = v_t.astype(jnp.float32).transpose(0, 2, 1, 3)
 
     # the Hydragen GEMM: every sharing query vs the whole node KV
-    s = jnp.einsum("shrd,shnd->shrn", qf, kf) * scale
+    s = jnp.einsum("shrd,shnd->shrn", qf, kf,
+                   precision=ref_mod.PRECISION) * scale
 
     off = jnp.arange(n, dtype=jnp.int32)
     valid = off[None, :] < ha.px_kvlen[:, None]           # (S, n) padding
@@ -160,7 +161,8 @@ def _prefix_phase(q, k_pool, v_pool, ha: HydragenArrays, window: int):
     m = jnp.max(s, axis=-1)
     p = jnp.exp(s - m[..., None]) * mask_r
     l = jnp.sum(p, axis=-1)
-    u = jnp.einsum("shrn,shnd->shrd", p, vf)
+    u = jnp.einsum("shrn,shnd->shrd", p, vf,
+                   precision=ref_mod.PRECISION)
     o = u / jnp.maximum(l, 1e-30)[..., None]
 
     def unfold(x):
@@ -199,7 +201,8 @@ def _suffix_phase(q, k_pool, v_pool, ha: HydragenArrays, window: int):
     kf = k_t.astype(jnp.float32).transpose(0, 2, 1, 3)    # (U, kv, n, d)
     vf = v_t.astype(jnp.float32).transpose(0, 2, 1, 3)
 
-    s = jnp.einsum("shgd,shnd->shgn", qf, kf) * scale     # (U, kv, g, n)
+    s = jnp.einsum("shgd,shnd->shgn", qf, kf,
+                   precision=ref_mod.PRECISION) * scale     # (U, kv, g, n)
 
     off = jnp.arange(n, dtype=jnp.int32)
     pos = ha.sf_pos[:, None].astype(jnp.int32) + off[None, :]   # (U, n)
@@ -213,7 +216,8 @@ def _suffix_phase(q, k_pool, v_pool, ha: HydragenArrays, window: int):
     m = jnp.max(s, axis=-1)
     p = jnp.exp(s - m[..., None]) * mask_r
     l = jnp.sum(p, axis=-1)
-    u = jnp.einsum("shgn,shnd->shgd", p, vf)
+    u = jnp.einsum("shgn,shnd->shgd", p, vf,
+                   precision=ref_mod.PRECISION)
     o = u / jnp.maximum(l, 1e-30)[..., None]
 
     def unfold(x):

@@ -4,7 +4,8 @@
 pool + a compiled ``DecodePlan`` -> attention outputs, with three
 interchangeable implementations:
 
-* ``pallas``  — the PAC kernel (interpret=True on CPU, compiled on TPU);
+* ``pallas``  — the PAC kernel (interpreted on the CPU backend, compiled
+                on a TPU: ``kernels.pallas_interpret`` decides);
 * ``xla``     — the same task/plan semantics expressed as dense jnp ops
                 (vectorised over tasks); this is what the distributed
                 serve_step lowers, so the multi-pod dry-run exercises the
@@ -25,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import pac as pac_mod
+from . import pallas_interpret
 from . import ref as ref_mod
 
 MASK_VALUE = ref_mod.MASK_VALUE
@@ -117,6 +119,34 @@ def gather_queries(q: jnp.ndarray, q_gather: jnp.ndarray) -> jnp.ndarray:
 
 
 # --------------------------------------------------------------------- #
+# GQA fold: (T+1, max_q, h_q, ...) <-> (T+1, n_kv * max_q * group, ...)
+# --------------------------------------------------------------------- #
+def fold_gqa(x: jnp.ndarray, n_kv: int) -> jnp.ndarray:
+    """Task-major ``(T+1, max_q, h_q, *tail)`` -> ``(T+1, rows, *tail)``.
+
+    Head ``h = kv * group + g`` of query slot ``qi`` lands on row
+    ``(kv * max_q + qi) * group + g``: each KV head's query rows are
+    contiguous, so one load of its K/V serves all of them.
+    """
+    Tp1, max_q, h_q = x.shape[:3]
+    tail = x.shape[3:]
+    group = h_q // n_kv
+    perm = (0, 2, 1, 3) + tuple(4 + i for i in range(len(tail)))
+    return (x.reshape(Tp1, max_q, n_kv, group, *tail).transpose(perm)
+             .reshape(Tp1, n_kv * max_q * group, *tail))
+
+
+def unfold_gqa(x: jnp.ndarray, max_q: int, n_kv: int) -> jnp.ndarray:
+    """Inverse of :func:`fold_gqa`."""
+    Tp1, rows = x.shape[:2]
+    tail = x.shape[2:]
+    group = rows // (n_kv * max_q)
+    perm = (0, 2, 1, 3) + tuple(4 + i for i in range(len(tail)))
+    return (x.reshape(Tp1, n_kv, max_q, group, *tail).transpose(perm)
+             .reshape(Tp1, max_q, n_kv * group, *tail))
+
+
+# --------------------------------------------------------------------- #
 # XLA implementation of PAC over the task-major plan arrays
 # --------------------------------------------------------------------- #
 def pac_xla(q_tasks: jnp.ndarray,     # (T+1, max_q, h_q, d)
@@ -138,14 +168,13 @@ def pac_xla(q_tasks: jnp.ndarray,     # (T+1, max_q, h_q, d)
     k_t = k_pool[task_pages].reshape(Tp1, n, n_kv, d)
     v_t = v_pool[task_pages].reshape(Tp1, n, n_kv, d)
 
-    qf = (q_tasks.astype(jnp.float32)
-          .reshape(Tp1, max_q, n_kv, group, d)
-          .transpose(0, 2, 1, 3, 4)
-          .reshape(Tp1, n_kv, max_q * group, d))
+    qf = fold_gqa(q_tasks.astype(jnp.float32), n_kv).reshape(
+        Tp1, n_kv, max_q * group, d)
     kf = k_t.astype(jnp.float32).transpose(0, 2, 1, 3)   # (T, n_kv, n, d)
     vf = v_t.astype(jnp.float32).transpose(0, 2, 1, 3)
 
-    s = jnp.einsum("thrd,thnd->thrn", qf, kf) * scale
+    s = jnp.einsum("thrd,thnd->thrn", qf, kf,
+                   precision=ref_mod.PRECISION) * scale
 
     off = jnp.arange(n, dtype=jnp.int32)
     pos = task_pos[:, None].astype(jnp.int32) + off[None, :]   # (T, n)
@@ -163,14 +192,13 @@ def pac_xla(q_tasks: jnp.ndarray,     # (T+1, max_q, h_q, d)
     m = jnp.max(s, axis=-1)
     p = jnp.exp(s - m[..., None]) * mask_r
     l = jnp.sum(p, axis=-1)
-    u = jnp.einsum("thrn,thnd->thrd", p, vf)
+    u = jnp.einsum("thrn,thnd->thrd", p, vf,
+                   precision=ref_mod.PRECISION)
     o = u / jnp.maximum(l, 1e-30)[..., None]
 
     def unfold(x):
-        tail = x.shape[3:]
-        return (x.reshape(Tp1, n_kv, max_q, group, *tail)
-                 .transpose(0, 2, 1, 3, *(4 + i for i in range(len(tail))))
-                 .reshape(Tp1, max_q, h_q, *tail))
+        return unfold_gqa(x.reshape(Tp1, n_kv * max_q * group, *x.shape[3:]),
+                          max_q, n_kv)
 
     return unfold(o), unfold(m), unfold(l)
 
@@ -185,17 +213,28 @@ def codec_partials_arrays(q: jnp.ndarray, k_pool: jnp.ndarray,
                           v_pool: jnp.ndarray, pa: PlanArrays,
                           num_queries: int, *, window: int = 0,
                           impl: str = "pallas",
-                          interpret: bool = True):
-    """Plan-covered attention -> per-query mergeable (o, m, l) stats."""
+                          interpret: Optional[bool] = None):
+    """Plan-covered attention -> per-query mergeable (o, m, l) stats.
+
+    ``interpret=None`` lets :func:`kernels.pallas_interpret` decide from
+    the backend; a compile for a described TPU passes ``False``.
+    """
     q_tasks = gather_queries(q, pa.q_gather)
     if impl == "pallas":
+        if interpret is None:
+            interpret = pallas_interpret()
+        max_q = q_tasks.shape[1]
+        n_kv = k_pool.shape[2]
+        qpos = jnp.broadcast_to(pa.q_pos[:, :, None, None],
+                                pa.q_pos.shape + (q.shape[1], 1))
         o, m, l = pac_mod.pac(
-            q_tasks, pa.q_pos, k_pool, v_pool,
+            fold_gqa(q_tasks, n_kv), fold_gqa(qpos, n_kv), k_pool, v_pool,
             pa.step_task, pa.step_page, pa.step_valid, pa.step_first,
             pa.step_last, pa.step_pos, pa.step_kvlen,
             window=window, interpret=interpret,
             num_lanes=pa.step_task.shape[0],
             max_steps=pa.step_task.shape[1])
+        o, m, l = (unfold_gqa(x, max_q, n_kv) for x in (o, m, l))
     elif impl == "xla":
         o, m, l = pac_xla(q_tasks, pa.q_pos, k_pool, v_pool,
                           pa.task_pages, pa.task_kvlen, pa.task_pos,
@@ -215,7 +254,7 @@ def codec_attention_arrays(q: jnp.ndarray, k_pool: jnp.ndarray,
                            v_pool: jnp.ndarray, pa: PlanArrays,
                            num_queries: int, *, window: int = 0,
                            impl: str = "pallas",
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: Optional[bool] = None) -> jnp.ndarray:
     out, _, _ = codec_partials_arrays(q, k_pool, v_pool, pa, num_queries,
                                       window=window, impl=impl,
                                       interpret=interpret)
@@ -223,7 +262,8 @@ def codec_attention_arrays(q: jnp.ndarray, k_pool: jnp.ndarray,
 
 
 def codec_attention(q, k_pool, v_pool, plan, *, impl: str = "pallas",
-                    window: int = 0, interpret: bool = True) -> jnp.ndarray:
+                    window: int = 0,
+                    interpret: Optional[bool] = None) -> jnp.ndarray:
     """Convenience entry taking a host DecodePlan object."""
     if impl == "ref":
         return ref_mod.codec_ref(q, k_pool, v_pool, plan).astype(q.dtype)

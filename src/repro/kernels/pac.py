@@ -16,9 +16,13 @@ Memory hierarchy mapping (GPU shared memory -> TPU VMEM):
   queries share them* (the paper's central IO saving).
 * The per-task query tile (pre-gathered, task-major) is fetched once per
   subtask: consecutive steps with an unchanged block index skip the DMA.
-* GQA: Q is folded to ``(n_kv, n_q*group, d)`` so each KV head's page is
-  used by all of its query groups in a single MXU pass — the paper's
-  GQA-aware load combining.
+* GQA: Q arrives folded to ``(n_kv * n_q * group, d)`` rows (the fold is
+  done in XLA by ``ops.fold_gqa``), and a page's K/V is viewed as
+  ``(page * n_kv, d)``, so one MXU pass scores every head's rows against
+  the page; a block-diagonal mask keeps each row to its own KV head.
+  Each KV page is loaded once for all of its query groups — the paper's
+  GQA-aware load combining — and the kernel needs no in-register
+  transpose, which Mosaic does not lower for these shapes.
 
 Outputs are *partial* results ``(o, m, l)`` per (task, query-slot); the
 tree reduction (ops.combine_partials) merges them per query.
@@ -35,9 +39,13 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import _CompilerParams
+from . import ref as ref_mod
 
-MASK_VALUE = -1e30
+MASK_VALUE = ref_mod.MASK_VALUE
+# m/l statistics are kept replicated across one vreg's lanes: a (rows, 1)
+# column is not a layout Mosaic stores efficiently (the idiom of the
+# Pallas TPU flash-attention kernel)
+STAT_LANES = 128
 
 
 def _pac_kernel(
@@ -45,21 +53,20 @@ def _pac_kernel(
     step_task, step_page, step_valid, step_first, step_last,
     step_pos, step_kvlen,
     # operand refs
-    q_ref,      # (1, max_q, h_q, d)
-    qpos_ref,   # (1, max_q)
+    q_ref,      # (1, rows, d)            rows = n_kv * max_q * group
+    qpos_ref,   # (1, rows, 1) int32      query position of each row
     k_ref,      # (1, page, n_kv, d)
     v_ref,      # (1, page, n_kv, d)
     # output refs
-    o_ref,      # (1, max_q, h_q, d) f32
-    m_ref,      # (1, max_q, h_q)   f32
-    l_ref,      # (1, max_q, h_q)   f32
+    o_ref,      # (1, rows, d) f32
+    m_ref,      # (1, rows, STAT_LANES) f32
+    l_ref,      # (1, rows, STAT_LANES) f32
     # scratch
-    acc,        # (n_kv, max_q*group, d) f32
-    m_s,        # (n_kv, max_q*group)    f32
-    l_s,        # (n_kv, max_q*group)    f32
+    acc,        # (rows, d) f32
+    m_s,        # (rows, STAT_LANES) f32
+    l_s,        # (rows, STAT_LANES) f32
     *,
     n_kv: int,
-    group: int,
     window: int,
 ):
     lane = pl.program_id(0)
@@ -76,70 +83,62 @@ def _pac_kernel(
 
     @pl.when(valid)
     def _step():
-        max_q = q_ref.shape[1]
-        d = q_ref.shape[3]
+        rows, d = q_ref.shape[1], q_ref.shape[2]
         page = k_ref.shape[1]
+        rows_per_head = rows // n_kv
         scale = 1.0 / np.sqrt(d)
 
-        q = q_ref[0].astype(jnp.float32)            # (max_q, h_q, d)
-        # fold GQA: head h = kv*group + g  ->  row = qi*group + g per kv
-        qf = (q.reshape(max_q, n_kv, group, d)
-                .transpose(1, 0, 2, 3)
-                .reshape(n_kv, max_q * group, d))
-        kf = k_ref[0].astype(jnp.float32).transpose(1, 0, 2)  # (n_kv, page, d)
-        vf = v_ref[0].astype(jnp.float32).transpose(1, 0, 2)
+        q = q_ref[0].astype(jnp.float32)                     # (rows, d)
+        # the page's heads side by side: column c = slot * n_kv + head
+        k = k_ref[0].astype(jnp.float32).reshape(page * n_kv, d)
+        v = v_ref[0].astype(jnp.float32).reshape(page * n_kv, d)
 
+        # one MXU pass for every head; a row only keeps the columns of
+        # its own KV head (block-diagonal mask below)
         s = jax.lax.dot_general(
-            qf, kf, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale       # (n_kv, R, page)
+            q, k, (((1,), (1,)), ((), ())), precision=ref_mod.PRECISION,
+            preferred_element_type=jnp.float32) * scale      # (rows, C)
 
-        # visibility mask (§4.1): page padding + causality + sliding window
-        pos = step_pos[lane, step] + jax.lax.broadcasted_iota(
-            jnp.int32, (max_q, page), 1)                      # (max_q, page)
-        kvlen = step_kvlen[lane, step]
-        qp = qpos_ref[0][:, None]                             # (max_q, 1)
-        mask = (pos < step_pos[lane, step] + kvlen) & (pos <= qp)
+        # visibility mask (§4.1): own head + page padding + causality +
+        # sliding window
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        slot = col // n_kv
+        pos = step_pos[lane, step] + slot
+        qp = qpos_ref[0]                                     # (rows, 1)
+        mask = ((row // rows_per_head == col % n_kv)
+                & (slot < step_kvlen[lane, step]) & (pos <= qp))
         if window > 0:
             mask = mask & (pos > qp - window)
-        mask_r = (jnp.broadcast_to(mask[:, None, :], (max_q, group, page))
-                    .reshape(1, max_q * group, page))
-        mask_r = jnp.broadcast_to(mask_r, (n_kv, max_q * group, page))
 
-        s = jnp.where(mask_r, s, MASK_VALUE)
-        m_new = jnp.maximum(m_s[...], jnp.max(s, axis=-1))    # (n_kv, R)
-        p = jnp.exp(s - m_new[..., None]) * mask_r            # masked -> 0
-        alpha = jnp.exp(m_s[...] - m_new)
-        l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=-1)
+        s = jnp.where(mask, s, MASK_VALUE)
+        m_prev = m_s[:, :1]                                  # (rows, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new) * mask                        # masked -> 0
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_s[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
         pv = jax.lax.dot_general(
-            p, vf, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)               # (n_kv, R, d)
-        acc[...] = acc[...] * alpha[..., None] + pv
-        m_s[...] = m_new
+            p, v, (((1,), (0,)), ((), ())), precision=ref_mod.PRECISION,
+            preferred_element_type=jnp.float32)              # (rows, d)
+        acc[...] = acc[...] * alpha + pv
+        m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
+        l_s[...] = jnp.broadcast_to(l_new, l_s.shape)
 
     @pl.when(last)
     def _finalize():
-        max_q = q_ref.shape[1]
-        d = q_ref.shape[3]
-        l_safe = jnp.maximum(l_s[...], 1e-30)
-        o = acc[...] / l_safe[..., None]                      # (n_kv, R, d)
-        # unfold GQA back to (max_q, h_q, ...)
-        o_ref[0] = (o.reshape(n_kv, max_q, group, d)
-                      .transpose(1, 0, 2, 3)
-                      .reshape(max_q, n_kv * group, d))
-        m_ref[0] = (m_s[...].reshape(n_kv, max_q, group)
-                      .transpose(1, 0, 2).reshape(max_q, n_kv * group))
-        l_ref[0] = (l_s[...].reshape(n_kv, max_q, group)
-                      .transpose(1, 0, 2).reshape(max_q, n_kv * group))
+        o_ref[0] = acc[...] / jnp.maximum(l_s[:, :1], 1e-30)
+        m_ref[0] = m_s[...]
+        l_ref[0] = l_s[...]
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("window", "interpret", "num_lanes", "max_steps"))
-def pac(q_tasks: jnp.ndarray,       # (T+1, max_q, h_q, d)
-        qpos_tasks: jnp.ndarray,    # (T+1, max_q) int32
-        k_pool: jnp.ndarray,        # (P, page, n_kv, d)
+def pac(q_rows: jnp.ndarray,       # (T+1, rows, d)
+        qpos_rows: jnp.ndarray,    # (T+1, rows, 1) int32
+        k_pool: jnp.ndarray,       # (P, page, n_kv, d)
         v_pool: jnp.ndarray,
-        step_task: jnp.ndarray,     # (num_lanes, max_steps) int32
+        step_task: jnp.ndarray,    # (num_lanes, max_steps) int32
         step_page: jnp.ndarray,
         step_valid: jnp.ndarray,
         step_first: jnp.ndarray,
@@ -148,75 +147,62 @@ def pac(q_tasks: jnp.ndarray,       # (T+1, max_q, h_q, d)
         step_kvlen: jnp.ndarray,
         *,
         window: int = 0,
-        interpret: bool = True,
+        interpret: bool,
         num_lanes: int = 2,
         max_steps: int = 1,
         ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Run the PAC kernel over a compiled DecodePlan's step arrays.
 
-    Returns task-major partials ``(o, m, l)`` of shapes
-    ``(T+1, max_q, h_q, d)``, ``(T+1, max_q, h_q)``, ``(T+1, max_q, h_q)``.
+    ``q_rows`` holds each task's queries GQA-folded (``ops.fold_gqa``):
+    row ``(kv * max_q + qi) * group + g`` is query slot ``qi``'s head
+    ``kv * group + g``, so every KV head's rows are contiguous.  Returns
+    task-major partials ``(o, m, l)`` in the same row order, of shapes
+    ``(T+1, rows, d)``, ``(T+1, rows)``, ``(T+1, rows)``.
     """
-    Tp1, max_q, h_q, d = q_tasks.shape
+    Tp1, rows, d = q_rows.shape
     _, page, n_kv, _ = k_pool.shape
-    group = h_q // n_kv
-    assert group * n_kv == h_q, (h_q, n_kv)
+    assert rows % n_kv == 0, (rows, n_kv)
 
-    grid = (num_lanes, max_steps)
-
-    def q_index(lane, step, st, *_):
-        return (st[lane, step], 0, 0, 0)
-
-    def qpos_index(lane, step, st, *_):
-        return (st[lane, step], 0)
+    def task_index(lane, step, st, *_):
+        return (st[lane, step], 0, 0)
 
     def kv_index(lane, step, st, sp, *_):
         return (sp[lane, step], 0, 0, 0)
 
-    def out_index(lane, step, st, *_):
-        return (st[lane, step], 0, 0, 0)
-
-    def ml_index(lane, step, st, *_):
-        return (st[lane, step], 0, 0)
-
-    kernel = functools.partial(_pac_kernel, n_kv=n_kv, group=group,
-                               window=window)
-
+    kernel = functools.partial(_pac_kernel, n_kv=n_kv, window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
-        grid=grid,
+        grid=(num_lanes, max_steps),
         in_specs=[
-            pl.BlockSpec((1, max_q, h_q, d), q_index),
-            pl.BlockSpec((1, max_q), qpos_index),
+            pl.BlockSpec((1, rows, d), task_index),
+            pl.BlockSpec((1, rows, 1), task_index),
             pl.BlockSpec((1, page, n_kv, d), kv_index),
             pl.BlockSpec((1, page, n_kv, d), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, max_q, h_q, d), out_index),
-            pl.BlockSpec((1, max_q, h_q), ml_index),
-            pl.BlockSpec((1, max_q, h_q), ml_index),
+            pl.BlockSpec((1, rows, d), task_index),
+            pl.BlockSpec((1, rows, STAT_LANES), task_index),
+            pl.BlockSpec((1, rows, STAT_LANES), task_index),
         ],
         scratch_shapes=[
-            pltpu.VMEM((n_kv, max_q * group, d), jnp.float32),
-            pltpu.VMEM((n_kv, max_q * group), jnp.float32),
-            pltpu.VMEM((n_kv, max_q * group), jnp.float32),
+            pltpu.VMEM((rows, d), jnp.float32),
+            pltpu.VMEM((rows, STAT_LANES), jnp.float32),
+            pltpu.VMEM((rows, STAT_LANES), jnp.float32),
         ],
     )
-
     out_shapes = [
-        jax.ShapeDtypeStruct((Tp1, max_q, h_q, d), jnp.float32),
-        jax.ShapeDtypeStruct((Tp1, max_q, h_q), jnp.float32),
-        jax.ShapeDtypeStruct((Tp1, max_q, h_q), jnp.float32),
+        jax.ShapeDtypeStruct((Tp1, rows, d), jnp.float32),
+        jax.ShapeDtypeStruct((Tp1, rows, STAT_LANES), jnp.float32),
+        jax.ShapeDtypeStruct((Tp1, rows, STAT_LANES), jnp.float32),
     ]
-
     o, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shapes,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(step_task, step_page, step_valid, step_first, step_last,
       step_pos, step_kvlen,
-      q_tasks, qpos_tasks, k_pool, v_pool)
-    return o, m, l
+      q_rows, qpos_rows, k_pool, v_pool)
+    return o, m[..., 0], l[..., 0]

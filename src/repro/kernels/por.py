@@ -12,14 +12,14 @@ cross-device sequence-parallel combine where exactly two partials meet.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import _CompilerParams
+from . import pallas_interpret
 from . import ref as ref_mod
 
 
@@ -43,7 +43,7 @@ def _por_kernel(o1_ref, m1_ref, l1_ref, o2_ref, m2_ref, l2_ref,
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def por(o1: jnp.ndarray, m1: jnp.ndarray, l1: jnp.ndarray,
         o2: jnp.ndarray, m2: jnp.ndarray, l2: jnp.ndarray,
-        *, block_rows: int = 128, interpret: bool = True,
+        *, block_rows: int = 128, interpret: Optional[bool] = None,
         ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Merge partials. o*: (N, h, d) f32; m*/l*: (N, h) f32."""
     n, h, d = o1.shape
@@ -63,9 +63,10 @@ def por(o1: jnp.ndarray, m1: jnp.ndarray, l1: jnp.ndarray,
             jax.ShapeDtypeStruct((n, h), jnp.float32),
             jax.ShapeDtypeStruct((n, h), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
-        interpret=interpret,
+        interpret=(pallas_interpret() if interpret is None
+                   else interpret),
     )(o1, m1, l1, o2, m2, l2)
 
 

@@ -14,6 +14,10 @@ import jax.numpy as jnp
 import numpy as np
 
 MASK_VALUE = -1e30
+# Attention math is float32 and every implementation keeps it so: on a
+# TPU, XLA's default precision would round f32 matmul operands to bf16,
+# and then backends that agree on the CPU would disagree on the chip.
+PRECISION = jax.lax.Precision.HIGHEST
 
 
 def _fold_gqa(q: jnp.ndarray, n_kv: int) -> jnp.ndarray:
@@ -55,7 +59,8 @@ def pac_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     qf = _fold_gqa(q.astype(jnp.float32), n_kv)              # (n_kv, R, d)
     kf = k.astype(jnp.float32).transpose(1, 0, 2)            # (n_kv, n, d)
     vf = v.astype(jnp.float32).transpose(1, 0, 2)
-    s = jnp.einsum("hrd,hnd->hrn", qf, kf) * scale           # (n_kv, R, n)
+    s = jnp.einsum("hrd,hnd->hrn", qf, kf,
+                   precision=PRECISION) * scale              # (n_kv, R, n)
 
     pos = pos_base + jnp.arange(n)
     valid = jnp.ones(n, bool) if kv_len is None else pos < pos_base + kv_len
@@ -74,7 +79,7 @@ def pac_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     m = jnp.max(s, axis=-1)                                  # (n_kv, R)
     p = jnp.exp(s - m[..., None]) * mask_r
     l = jnp.sum(p, axis=-1)
-    u = jnp.einsum("hrn,hnd->hrd", p, vf)
+    u = jnp.einsum("hrn,hnd->hrd", p, vf, precision=PRECISION)
     o = u / jnp.maximum(l, 1e-30)[..., None]
     return (_unfold_gqa(o, nq), _unfold_gqa(m, nq), _unfold_gqa(l, nq))
 

@@ -116,8 +116,6 @@ def collect_collectives(hlo_text: str, total_devices: int) -> CollectiveStats:
 
 def extract_cost(compiled) -> Dict[str, float]:
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):
-        ca = ca[0]
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
             "transcendentals": float(ca.get("transcendentals", 0.0))}

@@ -69,10 +69,12 @@ def init_params(cfg: ModelConfig, key, dtype=None) -> Params:
         "final_norm": L.norm_init(cfg.d_model, cfg, dtype),
     }
     if cfg.num_periods > 0:
+        # one period at a time, written straight into the stack: building
+        # per-period trees and stacking them would hold the layer
+        # weights twice
         pk = jax.random.split(k_blocks, cfg.num_periods)
-        stacked = [_period_init(pk[i], cfg, dtype)
-                   for i in range(cfg.num_periods)]
-        params["blocks"] = jax.tree.map(lambda *xs: jnp.stack(xs), *stacked)
+        params["blocks"] = jax.lax.map(
+            lambda k: _period_init(k, cfg, dtype), pk)
     if cfg.remainder_layers:
         rk = jax.random.split(k_rem, cfg.remainder_layers)
         params["rem"] = [
@@ -166,7 +168,7 @@ def mixer_offsets(cfg: ModelConfig) -> Tuple[List[int], List[int], int, int]:
     Returns ``(attn_off, mamba_off, attn_per_period, mamba_per_period)``;
     the dense ordinal of pattern position ``i`` in period ``pi`` is
     ``pi * attn_per_period + attn_off[i]`` — the same period-major order
-    ``serving.engine.flat_layers`` flattens to, i.e. the layer axis of
+    ``serving.engine.layer_kinds`` lists, i.e. the layer axis of
     the paged KV pool and of batched SSM state stacks.
     """
     attn_off, mamba_off = [], []
@@ -191,7 +193,7 @@ def scan_layer_stack(cfg: ModelConfig, params: Params, body, carry):
     what paged KV pools and batched recurrent-state stacks are indexed
     by.  Keeping the lowered HLO O(period) is what makes the fused
     decode step compile fast for deep models; ordering matches
-    ``serving.engine.flat_layers`` exactly.
+    ``serving.engine.layer_kinds`` exactly.
     """
     attn_off, mamba_off, A, M = mixer_offsets(cfg)
     pat = cfg.layer_pattern

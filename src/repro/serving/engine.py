@@ -160,16 +160,28 @@ class Request:
         return self.prompt + self.generated
 
 
-def flat_layers(cfg: ModelConfig, params) -> List[Tuple[LayerKind, Dict]]:
-    out = []
-    if cfg.num_periods > 0:
-        for pi in range(cfg.num_periods):
-            period = jax.tree.map(lambda x: x[pi], params["blocks"])
-            for i in range(cfg.period):
-                out.append((cfg.layer_pattern[i], period[f"sub{i}"]))
-    for i in range(cfg.remainder_layers):
-        out.append((cfg.layer_pattern[i], params["rem"][i]))
-    return out
+def layer_kinds(cfg: ModelConfig) -> List[LayerKind]:
+    """Kinds of the flat layers, period-major: the order of the paged KV
+    pool's layer axis and of ``transformer.scan_layer_stack``."""
+    return ([cfg.layer_pattern[i] for _ in range(cfg.num_periods)
+             for i in range(cfg.period)]
+            + [cfg.layer_pattern[i] for i in range(cfg.remainder_layers)])
+
+
+@jax.jit
+def _period_slice(stacked, pi):
+    return jax.tree.map(lambda x: x[pi], stacked)
+
+
+def layer_params(cfg: ModelConfig, params, j: int) -> Dict:
+    """Parameters of flat layer ``j``, sliced out of the period stack
+    when it is used: the eager paths hold one layer at a time, so the
+    weights are never held twice."""
+    n_stacked = cfg.num_periods * cfg.period
+    if j >= n_stacked:
+        return params["rem"][j - n_stacked]
+    pi, i = divmod(j, cfg.period)
+    return _period_slice(params["blocks"][f"sub{i}"], np.int32(pi))
 
 
 class DecodeEngine:
@@ -292,9 +304,9 @@ class DecodeEngine:
         self._drafts: Dict[int, spec_mod.DraftState] = {}
         self._next_virt = -2          # virtual branch-head query ids
 
-        self.layers = flat_layers(cfg, params)
+        self.layer_kinds = layer_kinds(cfg)
         self.attn_layer_idx = {j: a for a, j in enumerate(
-            j for j, (k, _) in enumerate(self.layers)
+            j for j, k in enumerate(self.layer_kinds)
             if k.mixer in ("attn", "attn_local"))}
         n_attn = len(self.attn_layer_idx)
         if mesh is not None:
@@ -387,7 +399,7 @@ class DecodeEngine:
         # satisfy the registry's jit-safe partials contract (``ref``
         # falls back to the eager per-layer path).
         self.fused = bool(fused) and self._backend.jit_safe
-        self._mamba_layer_js = [j for j, (k, _) in enumerate(self.layers)
+        self._mamba_layer_js = [j for j, k in enumerate(self.layer_kinds)
                                 if k.mixer == "mamba"]
         self._step_fn = None
         self._spec_step_fn = None
@@ -408,6 +420,9 @@ class DecodeEngine:
             self._replicated_sharding = jax.sharding.NamedSharding(
                 mesh, jax.sharding.PartitionSpec())
             self.key = jax.device_put(self.key, self._replicated_sharding)
+            # weights live replicated on every device once; left
+            # uncommitted, each dispatch would copy them to the mesh
+            self.params = jax.device_put(params, self._replicated_sharding)
         elif self.fused:
             self._step_fn = step_fn_mod.make_step_fn(
                 cfg, self._backend, tuple(self._windows()), temperature)
@@ -760,7 +775,7 @@ class DecodeEngine:
 
         # ---- phase B: per-request suffix chunks, one dispatch --------- #
         alive = [r for r in alive if self.requests[r].state == PREFILL]
-        has_mamba = any(k.mixer == "mamba" for k, _ in self.layers)
+        has_mamba = any(k.mixer == "mamba" for k in self.layer_kinds)
         by_key: Dict[int, List[int]] = {}
         for rid in alive:
             key = self._cascade_key(rid)
@@ -844,7 +859,8 @@ class DecodeEngine:
 
         x = T._embed(self.params, cfg, jnp.asarray(tok), qpos_j)
         new_kv_writes = []   # (layer_attn, k (B_pad,T_pad,kv,hd), v)
-        for j, (kind, p) in enumerate(self.layers):
+        for j, kind in enumerate(self.layer_kinds):
+            p = layer_params(cfg, self.params, j)
             h = L.apply_norm(p["ln"], x, cfg)
             la = self.attn_layer_idx[j]
             window = (cfg.sliding_window if kind.mixer == "attn_local"
@@ -1617,7 +1633,7 @@ class DecodeEngine:
         # contiguous filled-KV front along the path
         kv_filled = self._filled_front(rid)
 
-        has_mamba = any(k.mixer == "mamba" for k, _ in self.layers)
+        has_mamba = any(k.mixer == "mamba" for k in self.layer_kinds)
 
         if kv_filled < total:
             attn_start = kv_filled
@@ -1686,7 +1702,8 @@ class DecodeEngine:
         leaf_id = self.forest.leaf_of[rid]
 
         new_kv_writes = []  # (layer_attn, k (Tn,kv,hd), v)
-        for j, (kind, p) in enumerate(self.layers):
+        for j, kind in enumerate(self.layer_kinds):
+            p = layer_params(cfg, self.params, j)
             h = L.apply_norm(p["ln"], x, cfg)
             if kind.mixer in ("attn", "attn_local"):
                 la = self.attn_layer_idx[j]
@@ -1809,7 +1826,7 @@ class DecodeEngine:
     # ------------------------------------------------------------------ #
     def _windows(self) -> List[int]:
         ws = set()
-        for kind, _ in self.layers:
+        for kind in self.layer_kinds:
             if kind.mixer == "attn":
                 ws.add(0)
             elif kind.mixer == "attn_local":
@@ -1826,13 +1843,28 @@ class DecodeEngine:
         """Compiled fused-step program count (jit cache entries); the
         compile-cache regression test bounds this by the number of
         distinct ``bucket_signatures``."""
-        # _cache_size is a private jax API (stable across the pinned
-        # 0.4.x line); degrade to 0 rather than crash stats printing if
-        # a future jax renames it
+        # _cache_size is a private jax API; degrade to 0 rather than
+        # crash stats printing if a future jax renames it
         fn = self._step_fn if self._step_fn is not None \
             else self._spec_step_fn
         size = getattr(fn, "_cache_size", None)
         return int(size()) if callable(size) else 0
+
+    def lower_fused_step(self):
+        """Lower the fused decode step at the current epoch's shapes.
+
+        Lowers only (nothing is dispatched or donated); the text shows
+        what the step contains, e.g. a ``tpu_custom_call`` per compiled
+        Pallas kernel.  Needs a fused engine that has decoded a step.
+        """
+        if self._step_fn is None or self._fused_base is None:
+            raise RuntimeError("no fused decode step has run yet")
+        state = step_fn_mod.StepState(self.pool.k, self.pool.v,
+                                      *self._mamba_carry)
+        tokens = jnp.zeros(self._fused_bucket, jnp.int32)
+        return self._step_fn.lower(
+            self.params, state, tokens, self.key, self._fused_base,
+            np.int32(self._fused_delta), self._fused_prepared)
 
     def _rebuild_plans(self) -> None:
         t0 = time.perf_counter()
@@ -2189,7 +2221,8 @@ class DecodeEngine:
         tail_base = jnp.asarray(np.asarray(tail_base), jnp.int32)
         tail_off = jnp.asarray(np.asarray(tail_off), jnp.int32)
 
-        for j, (kind, p) in enumerate(self.layers):
+        for j, kind in enumerate(self.layer_kinds):
+            p = layer_params(cfg, self.params, j)
             h = L.apply_norm(p["ln"], x, cfg)
             if kind.mixer in ("attn", "attn_local"):
                 la = self.attn_layer_idx[j]
@@ -2618,7 +2651,7 @@ class DecodeEngine:
         ps = self.page_size
         lanes = max(self.num_lanes, 1)
         n_attn_w = {w: 0 for w in self._windows()}
-        for kind, _ in self.layers:
+        for kind in self.layer_kinds:
             if kind.mixer in ("attn", "attn_local"):
                 w = (self.cfg.sliding_window if kind.mixer == "attn_local"
                      else 0)
@@ -2913,7 +2946,8 @@ class DecodeEngine:
         prepared = {w: self._backend.prepare(p) for w, p in plans.items()}
         x = T._embed(self.params, cfg, jnp.asarray(tokens)[None].T,
                      qp[:, None])                            # (B,1,d)
-        for j, (kind, p) in enumerate(self.layers):
+        for j, kind in enumerate(self.layer_kinds):
+            p = layer_params(cfg, self.params, j)
             h = L.apply_norm(p["ln"], x, cfg)
             if kind.mixer in ("attn", "attn_local"):
                 la = self.attn_layer_idx[j]

@@ -10,6 +10,7 @@ them directly — the CoDec kernel follows this exact layout.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -86,6 +87,14 @@ class PageAllocator:
             raise AssertionError("non-positive refcount")
 
 
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _scatter_tokens(k, v, li, pg, of, k_new, v_new):
+    """In-place scatter into the donated pools: an eager ``.at[].set``
+    would hold a second copy of the whole pool while it runs."""
+    return (k.at[li, pg, of].set(k_new.astype(k.dtype)),
+            v.at[li, pg, of].set(v_new.astype(v.dtype)))
+
+
 class PagedKVPool:
     """Device-resident paged pool for all attention layers."""
 
@@ -133,8 +142,8 @@ class PagedKVPool:
         li = jnp.full(pages.shape, layer, jnp.int32)
         pg = jnp.asarray(pages, jnp.int32)
         of = jnp.asarray(offsets, jnp.int32)
-        self.k = self.k.at[li, pg, of].set(k_new.astype(self.k.dtype))
-        self.v = self.v.at[li, pg, of].set(v_new.astype(self.v.dtype))
+        self.k, self.v = _scatter_tokens(self.k, self.v, li, pg, of,
+                                         k_new, v_new)
 
     def copy_slots(self, src_pages: np.ndarray, src_offs: np.ndarray,
                    dst_pages: np.ndarray, dst_offs: np.ndarray) -> None:

@@ -160,10 +160,8 @@ MINI_DRYRUN = textwrap.dedent("""\
     from repro.training import trainer
     from repro.training.optimizer import cosine_schedule, make_optimizer
 
-    kw = {}
-    if hasattr(jax.sharding, "AxisType"):   # added in newer jax
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * 2
-    mesh = jax.make_mesh((2, 2), ("data", "model"), **kw)
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = smoke_config("qwen2.5-14b")
     opt = make_optimizer("adamw", cosine_schedule(1e-3, 2, 10))
     step = trainer.make_train_step(cfg, opt, remat=False)
@@ -181,8 +179,6 @@ MINI_DRYRUN = textwrap.dedent("""\
         compiled = jax.jit(step).lower(state, (tok, tok)).compile()
     print("MEM", compiled.memory_analysis().temp_size_in_bytes)
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):                # old jax wraps it in a list
-        ca = ca[0]
     print("FLOPS", ca["flops"])
     print("DRYRUN_OK")
 """)
@@ -196,3 +192,24 @@ def test_mini_dryrun_subprocess(tmp_path):
     r = subprocess.run([sys.executable, str(script)], env=env,
                        capture_output=True, text=True, timeout=600)
     assert "DRYRUN_OK" in r.stdout, r.stderr[-2000:]
+
+
+# --------------------------------------------------------------------- #
+# serving launcher exit status
+# --------------------------------------------------------------------- #
+@pytest.mark.parametrize("max_steps,rc", [(0, 0), (2, 1)],
+                         ids=["finished", "unfinished"])
+def test_serve_exit_code_reports_unfinished(tmp_path, max_steps, rc):
+    """A run that leaves requests unfinished must not exit 0."""
+    env = dict(os.environ, PYTHONPATH=os.path.abspath(SRC),
+               JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    r = subprocess.run(
+        [sys.executable, "-m", "repro.launch.serve", "--arch", "qwen3-4b",
+         "--smoke", "--backend", "codec-xla", "--requests", "2",
+         "--doc-len", "32", "--max-new", "4", "--no-telemetry",
+         "--max-steps", str(max_steps)],
+        env=env, capture_output=True, text=True, timeout=300)
+    assert r.returncode == rc, r.stdout[-2000:] + r.stderr[-2000:]
+    assert ("unfinished" in r.stdout) == bool(rc)
+

@@ -387,7 +387,6 @@ POR_PROPERTY = textwrap.dedent("""\
     import numpy as np
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.kernels import por
     from repro.kernels.ref import MASK_VALUE
@@ -419,10 +418,11 @@ POR_PROPERTY = textwrap.dedent("""\
             return ro[None], rm[None], rl[None]
         # contrib is a TRACED argument, exactly as the engine passes it:
         # ONE compiled program serves every ownership mask
-        f_sub = jax.jit(shard_map(sub, mesh=mesh, in_specs=spec + (P(),),
-                                  out_specs=spec, check_rep=False))
-        f_full = jax.jit(shard_map(full, mesh=mesh, in_specs=spec,
-                                   out_specs=spec, check_rep=False))
+        f_sub = jax.jit(jax.shard_map(sub, mesh=mesh,
+                                      in_specs=spec + (P(),),
+                                      out_specs=spec, check_vma=False))
+        f_full = jax.jit(jax.shard_map(full, mesh=mesh, in_specs=spec,
+                                       out_specs=spec, check_vma=False))
         return f_sub, f_full
 
     for D in (1, 2, 4):
