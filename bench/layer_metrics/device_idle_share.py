@@ -1,0 +1,9 @@
+"""Device: share of the traced window in which no operation ran on the
+chip (1 - the union of busy intervals over the window)."""
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    if not tr["window_s"]:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
