@@ -35,8 +35,9 @@ def measured_smoke() -> None:
             eng.add_request(p, max_new=6)
         # wall-clock TPOT with a terminal device sync, started after the
         # first step so prefill + cold jit compiles are excluded: on the
-        # fused path stats["decode_time"] alone would only cover host
-        # dispatch + boundary syncs (async compute surfaces at the block)
+        # fused path the engine's host timers (decode_dispatch_time,
+        # decode_sync_time) cover only dispatch + boundary syncs (async
+        # compute surfaces at the block)
         eng.step()
         t0 = time.perf_counter()
         eng.run(6)

@@ -69,7 +69,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Dict, List, Optional, Set, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -244,8 +244,10 @@ class DecodeEngine:
         # ---- fault tolerance (serving/faults.py, DESIGN.md §12) ------- #
         # clock: injectable monotonic time source — deadlines are
         # enforced against it at step boundaries, so tests and the chaos
-        # harness drive it deterministically (e.g. one unit per step)
-        self.clock = clock if clock is not None else time.monotonic
+        # harness drive it deterministically (e.g. one unit per step).
+        # The default is the host clock the engine's spans read
+        # (telemetry.Span), so Chrome events share their readings.
+        self.clock = clock if clock is not None else time.perf_counter
         # ---- telemetry (serving/telemetry.py, DESIGN.md §13) ---------- #
         # telemetry=True builds a default Telemetry; pass an instance to
         # set profile_every / inject a TraceSink.  Span timestamps ride
@@ -257,6 +259,8 @@ class DecodeEngine:
             telemetry or None
         if self.telemetry is not None:
             self.telemetry.bind_clock(self.clock)
+        # prefill's program builds are counted by one listener per process
+        telemetry_mod.listen_for_builds()
         self.nan_guard = bool(nan_guard)
         if self.nan_guard and mesh is not None:
             raise ValueError(
@@ -376,8 +380,10 @@ class DecodeEngine:
         self.replan_interval = replan_interval
         self._steps_since_plan = 0
         self.stats = {"steps": 0, "replans": 0, "plan_time": 0.0,
-                      "decode_time": 0.0, "decode_dispatch_time": 0.0,
+                      "decode_dispatch_time": 0.0,
                       "decode_sync_time": 0.0, "prefill_tokens": 0,
+                      "prefill_s": 0.0, "prefill_build_s": 0.0,
+                      "prefill_builds": 0,
                       "admitted": 0, "preempted": 0, "reclaimed": 0,
                       "recompute_tokens": 0, "prefill_chunks": 0,
                       "prefill_stalls": 0, "fused_calls": 0,
@@ -585,12 +591,13 @@ class DecodeEngine:
             # on memory does NOT cost the fused path a sync per step.
             self.flush_tokens()
             self.admission.pop()
-            self._admit(head)
-            group = [head.rid]
-            if self.policy.cascade:
-                group += self._co_admit_partners(head)
-            spent += self._prefill_group(
-                group, None if budget is None else budget - spent)
+            with telemetry_mod.Span("codec.admit"):
+                self._admit(head)
+                group = [head.rid]
+                if self.policy.cascade:
+                    group += self._co_admit_partners(head)
+                spent += self._prefill_group(
+                    group, None if budget is None else budget - spent)
 
     def _advance_prefills(self, budget: Optional[int]) -> int:
         """Advance every admitted-but-unfinished prefill by one chunk."""
@@ -732,7 +739,6 @@ class DecodeEngine:
         its siblings proceed; a stall on the *shared* span stalls the
         group (the span is on every member's path).
         """
-        tm = self.telemetry
         spent = 0
         alive = [r for r in group if self.requests[r].state == PREFILL]
         if len(alive) < 2:
@@ -743,18 +749,13 @@ class DecodeEngine:
         lead = self.requests[alive[0]]
         frontier = self._shared_frontier(alive)
         if self._filled_front(lead.rid) < frontier:
-            c0 = self.clock() if tm is not None else 0.0
-            n = self._prefill_step(lead, budget, stop_at=frontier)
+            # the shared chunk belongs to every member's prefill span,
+            # not just the lead's (§13 nesting)
+            n = self._prefill_step(lead, budget, stop_at=frontier,
+                                   shared_with=alive[1:])
             spent += n
             if n:
                 self.stats["cascade_shared_tokens"] += n * (len(alive) - 1)
-                if tm is not None:
-                    c1 = self.clock()
-                    for rid in alive[1:]:
-                        # the shared chunk belongs to every member's
-                        # prefill span, not just the lead's (§13 nesting)
-                        tm.complete("prefill_chunk", c0, c1, track=rid,
-                                    args={"tokens": n, "shared": True})
             # hand each sibling the carried mid-node SSM state so hybrid
             # archs resume from the cascaded chunk boundary instead of
             # recomputing from the last node-aligned ``meta["ssm"]``
@@ -827,130 +828,143 @@ class DecodeEngine:
         """
         cfg = self.cfg
         tm = self.telemetry
-        c0 = self.clock() if tm is not None else 0.0
         B = len(batch)
         Tn = [end - start for _, start, end in batch]
-        T_pad = plan_mod.bucket_pow2(max(Tn))
-        S_pad = plan_mod.bucket_pow2(max(end for _, _, end in batch))
-        B_pad = plan_mod.bucket_pow2(B)
-
-        tok = np.zeros((B_pad, T_pad), np.int32)
-        qpos = np.full((B_pad, T_pad), -1, np.int32)
-        kv_valid = np.zeros((B_pad, S_pad), bool)
-        for i, (req, start, end) in enumerate(batch):
-            tok[i, :Tn[i]] = req.seq[start:end]
-            qpos[i, :Tn[i]] = start + np.arange(Tn[i])
-            kv_valid[i, :end] = True
-        kv_pos = np.broadcast_to(np.arange(S_pad, dtype=np.int32),
-                                 (B_pad, S_pad))
-        qpos_j = jnp.asarray(qpos)
-
-        paths = {req.rid: self.forest.path(req.rid) for req, _, _ in batch}
-        segments: Dict[int, List[Tuple[Any, int, int]]] = {}
-        for req, start, end in batch:
-            segs, off = [], 0
-            for node in paths[req.rid]:
-                lo = max(0, off - start)
-                hi = min(end, off + node.length) - start
-                if hi > lo:
-                    segs.append((node, lo, hi))
-                off += node.length
-            segments[req.rid] = segs
-
-        x = T._embed(self.params, cfg, jnp.asarray(tok), qpos_j)
-        new_kv_writes = []   # (layer_attn, k (B_pad,T_pad,kv,hd), v)
-        for j, kind in enumerate(self.layer_kinds):
-            p = layer_params(cfg, self.params, j)
-            h = L.apply_norm(p["ln"], x, cfg)
-            la = self.attn_layer_idx[j]
-            window = (cfg.sliding_window if kind.mixer == "attn_local"
-                      else 0)
-            q, k_new, v_new = L.attn_project(p["attn"], cfg, h, qpos_j)
-            k_rows, v_rows = [], []
-            for i, (req, start, end) in enumerate(batch):
-                pk, pv = self._gather_prefix_upto(la, paths[req.rid],
-                                                  start)
-                kr = jnp.concatenate([pk.astype(k_new.dtype),
-                                      k_new[i, :Tn[i]]], 0)
-                vr = jnp.concatenate([pv.astype(v_new.dtype),
-                                      v_new[i, :Tn[i]]], 0)
-                pad = S_pad - kr.shape[0]
-                if pad:
-                    kr = jnp.pad(kr, ((0, pad), (0, 0), (0, 0)))
-                    vr = jnp.pad(vr, ((0, pad), (0, 0), (0, 0)))
-                k_rows.append(kr)
-                v_rows.append(vr)
-            k_all = jnp.stack(k_rows, 0)
-            v_all = jnp.stack(v_rows, 0)
-            if B_pad > B:
-                zpad = ((0, B_pad - B), (0, 0), (0, 0), (0, 0))
-                k_all = jnp.pad(k_all, zpad)
-                v_all = jnp.pad(v_all, zpad)
-            o = L.mha(q, k_all, v_all, causal=True, window=window,
-                      softcap=cfg.attn_logit_softcap,
-                      q_positions=qpos_j,
-                      kv_positions=jnp.asarray(kv_pos),
-                      kv_valid=jnp.asarray(kv_valid))
-            y = L.dense(p["attn"]["wo"],
-                        o.reshape(B_pad, T_pad,
-                                  cfg.num_heads * cfg.head_dim))
-            new_kv_writes.append((la, k_new, v_new))
-            x = x + y
-            x, _ = L.apply_ffn_block(p, cfg, kind.ffn, x)
-
-        # write each row's new KV into its own nodes' unfilled slots
-        ps = self.page_size
-        pages, offs, rows_b, rows_t = [], [], [], []
-        for i, (req, start, end) in enumerate(batch):
-            for node, lo, hi in segments[req.rid]:
-                filled = node.meta.get("filled", 0)
-                base = node.start_pos - start
-                t_hi = hi - base
-                reps = node.meta.get("replicas")
-                page_lists = (list(reps.values()) if reps
-                              else [node.page_ids])
-                for t in range(max(filled, lo - base), t_hi):
-                    for pl in page_lists:
-                        pages.append(pl[t // ps])
-                        offs.append(t % ps)
-                        rows_b.append(i)
-                        rows_t.append(base + t)
-                if t_hi > filled:
-                    node.meta["filled"] = t_hi
-        if pages:
-            bi = jnp.asarray(np.asarray(rows_b))
-            ti = jnp.asarray(np.asarray(rows_t))
-            for la, k_new, v_new in new_kv_writes:
-                self.pool.write_tokens(la, np.asarray(pages),
-                                       np.asarray(offs),
-                                       k_new[bi, ti], v_new[bi, ti])
-
         done = sum(Tn)
-        self.stats["prefill_tokens"] += done
-        self.stats["cascade_suffix_tokens"] += done
-        self.stats["cascade_batches"] += 1
-        logits_all = None
-        for i, (req, start, end) in enumerate(batch):
-            self.stats["recompute_tokens"] += max(
-                0, min(end, req.computed_hwm) - start)
-            req.computed_hwm = max(req.computed_hwm, end)
-            if end < len(req.seq):
-                self.stats["prefill_chunks"] += 1
-                continue
-            if req.pending is None:
-                if logits_all is None:
-                    logits_all = T._unembed(self.params, cfg, x)
-                logits = logits_all[i, Tn[i] - 1]
-                self.key, sk = jax.random.split(self.key)
-                req.pending = int(sampler.sample(logits[None], sk,
-                                                 self.temperature)[0])
-            self._promote(req)
+        finished: List[Request] = []
+        with telemetry_mod.PrefillSpan(
+                self.stats, tm, track=batch[0][0].rid,
+                args={"tokens": Tn[0], "batched": True}) as sp:
+            T_pad = plan_mod.bucket_pow2(max(Tn))
+            S_pad = plan_mod.bucket_pow2(max(end for _, _, end in batch))
+            B_pad = plan_mod.bucket_pow2(B)
+
+            tok = np.zeros((B_pad, T_pad), np.int32)
+            qpos = np.full((B_pad, T_pad), -1, np.int32)
+            kv_valid = np.zeros((B_pad, S_pad), bool)
+            for i, (req, start, end) in enumerate(batch):
+                tok[i, :Tn[i]] = req.seq[start:end]
+                qpos[i, :Tn[i]] = start + np.arange(Tn[i])
+                kv_valid[i, :end] = True
+            kv_pos = np.broadcast_to(np.arange(S_pad, dtype=np.int32),
+                                     (B_pad, S_pad))
+            qpos_j = jnp.asarray(qpos)
+
+            paths = {req.rid: self.forest.path(req.rid)
+                     for req, _, _ in batch}
+            segments: Dict[int, List[Tuple[Any, int, int]]] = {}
+            for req, start, end in batch:
+                segs, off = [], 0
+                for node in paths[req.rid]:
+                    lo = max(0, off - start)
+                    hi = min(end, off + node.length) - start
+                    if hi > lo:
+                        segs.append((node, lo, hi))
+                    off += node.length
+                segments[req.rid] = segs
+
+            x = T._embed(self.params, cfg, jnp.asarray(tok), qpos_j)
+            new_kv_writes = []   # (layer_attn, k (B_pad,T_pad,kv,hd), v)
+            for j, kind in enumerate(self.layer_kinds):
+                p = layer_params(cfg, self.params, j)
+                h = L.apply_norm(p["ln"], x, cfg)
+                la = self.attn_layer_idx[j]
+                window = (cfg.sliding_window if kind.mixer == "attn_local"
+                          else 0)
+                q, k_new, v_new = L.attn_project(p["attn"], cfg, h, qpos_j)
+                with telemetry_mod.Span("codec.prefill.gather"):
+                    k_rows, v_rows = [], []
+                    for i, (req, start, end) in enumerate(batch):
+                        pk, pv = self._gather_prefix_upto(
+                            la, paths[req.rid], start)
+                        kr = jnp.concatenate([pk.astype(k_new.dtype),
+                                              k_new[i, :Tn[i]]], 0)
+                        vr = jnp.concatenate([pv.astype(v_new.dtype),
+                                              v_new[i, :Tn[i]]], 0)
+                        pad = S_pad - kr.shape[0]
+                        if pad:
+                            kr = jnp.pad(kr, ((0, pad), (0, 0), (0, 0)))
+                            vr = jnp.pad(vr, ((0, pad), (0, 0), (0, 0)))
+                        k_rows.append(kr)
+                        v_rows.append(vr)
+                    k_all = jnp.stack(k_rows, 0)
+                    v_all = jnp.stack(v_rows, 0)
+                    if B_pad > B:
+                        zpad = ((0, B_pad - B), (0, 0), (0, 0), (0, 0))
+                        k_all = jnp.pad(k_all, zpad)
+                        v_all = jnp.pad(v_all, zpad)
+                with telemetry_mod.Span("codec.prefill.attn"):
+                    o = L.mha(q, k_all, v_all, causal=True, window=window,
+                              softcap=cfg.attn_logit_softcap,
+                              q_positions=qpos_j,
+                              kv_positions=jnp.asarray(kv_pos),
+                              kv_valid=jnp.asarray(kv_valid))
+                y = L.dense(p["attn"]["wo"],
+                            o.reshape(B_pad, T_pad,
+                                      cfg.num_heads * cfg.head_dim))
+                new_kv_writes.append((la, k_new, v_new))
+                x = x + y
+                x, _ = L.apply_ffn_block(p, cfg, kind.ffn, x)
+
+            # write each row's new KV into its own nodes' unfilled slots
+            with telemetry_mod.Span("codec.prefill.kv_write"):
+                ps = self.page_size
+                pages, offs, rows_b, rows_t = [], [], [], []
+                for i, (req, start, end) in enumerate(batch):
+                    for node, lo, hi in segments[req.rid]:
+                        filled = node.meta.get("filled", 0)
+                        base = node.start_pos - start
+                        t_hi = hi - base
+                        reps = node.meta.get("replicas")
+                        page_lists = (list(reps.values()) if reps
+                                      else [node.page_ids])
+                        for t in range(max(filled, lo - base), t_hi):
+                            for pl in page_lists:
+                                pages.append(pl[t // ps])
+                                offs.append(t % ps)
+                                rows_b.append(i)
+                                rows_t.append(base + t)
+                        if t_hi > filled:
+                            node.meta["filled"] = t_hi
+                if pages:
+                    bi = jnp.asarray(np.asarray(rows_b))
+                    ti = jnp.asarray(np.asarray(rows_t))
+                    for la, k_new, v_new in new_kv_writes:
+                        self.pool.write_tokens(la, np.asarray(pages),
+                                               np.asarray(offs),
+                                               k_new[bi, ti], v_new[bi, ti])
+
+            self.stats["prefill_tokens"] += done
+            self.stats["cascade_suffix_tokens"] += done
+            self.stats["cascade_batches"] += 1
+            logits_all = None
+            for i, (req, start, end) in enumerate(batch):
+                self.stats["recompute_tokens"] += max(
+                    0, min(end, req.computed_hwm) - start)
+                req.computed_hwm = max(req.computed_hwm, end)
+                if end < len(req.seq):
+                    self.stats["prefill_chunks"] += 1
+                    continue
+                if req.pending is None:
+                    with telemetry_mod.Span("codec.prefill.sample"):
+                        if logits_all is None:
+                            logits_all = T._unembed(self.params, cfg, x)
+                        logits = logits_all[i, Tn[i] - 1]
+                        self.key, sk = jax.random.split(self.key)
+                        req.pending = int(sampler.sample(
+                            logits[None], sk, self.temperature)[0])
+                finished.append(req)
         if tm is not None:
-            c1 = self.clock()
-            for i, (req, _, _) in enumerate(batch):
-                tm.complete("prefill_chunk", c0, c1, track=req.rid,
+            # the span recorded row 0's chunk; the other rows' chunks
+            # share its readings
+            for i, (req, _, _) in enumerate(batch[1:], 1):
+                tm.complete("prefill_chunk", sp.c0, sp.c1, track=req.rid,
                             args={"tokens": Tn[i], "batched": True})
-            tm.observe("prefill_chunk_s", c1 - c0)
+            tm.observe("prefill_chunk_s", sp.c1 - sp.c0)
+        # promote after the chunk spans close: each request's "prefill"
+        # span ends at promotion and must contain its chunks
+        for req in finished:
+            self._promote(req)
         return done
 
     def _admit(self, req: Request) -> None:
@@ -999,8 +1013,34 @@ class DecodeEngine:
         if not self._deferred and not self._pending_ref:
             return
         tm = self.telemetry
-        c0 = self.clock() if tm is not None else 0.0
-        t0 = time.perf_counter()
+        with telemetry_mod.Span("codec.flush", tm, "flush") as sp:
+            landed, poisoned = self._land_deferred()
+            sp.args = {"tokens": len(landed)}
+        elapsed = sp.seconds
+        self.stats["token_flushes"] += 1
+        self.stats["decode_sync_time"] += elapsed
+        # the sync wait is attributed to the step in which the flush
+        # actually ran, under its OWN key — it must never pollute that
+        # step's dispatch/compute split (async flushing defers syncs to
+        # arbitrary later steps; see step_stats "flush_time")
+        self._decode_timing["flush_time"] = \
+            self._decode_timing.get("flush_time", 0.0) + elapsed
+        if tm is not None:
+            for rid in landed:
+                self._note_token(self.requests[rid])
+            tm.observe("flush_s", elapsed)
+        for rid, cut in poisoned.items():
+            req = self.requests.get(rid)
+            if req is None:
+                continue
+            del req.generated[cut:]       # placeholders only (never -1
+            self.stats["nan_rows"] += 1   # streamed, so emitted <= cut)
+            self._fail_request(rid, "nan_logits", flush=False)
+
+    def _land_deferred(self) -> Tuple[Set[int], Dict[int, int]]:
+        """Write every deferred device token's value into the streams and
+        the forest; returns the requests whose tokens landed and, under
+        the NaN guard, each poisoned request's first bad index."""
         vals = {id(e): np.asarray(e.tokens) for e in self._deferred}
         # NaN guard: a dispatch whose row_ok flag is False produced
         # non-finite logits for that row — every token of that request
@@ -1046,28 +1086,7 @@ class DecodeEngine:
         self._deferred = []
         self._pending_ref = {}
         self._flushed_since_dispatch = True
-        self.stats["token_flushes"] += 1
-        elapsed = time.perf_counter() - t0
-        self.stats["decode_sync_time"] += elapsed
-        # the sync wait is attributed to the step in which the flush
-        # actually ran, under its OWN key — it must never pollute that
-        # step's dispatch/compute split (async flushing defers syncs to
-        # arbitrary later steps; see step_stats "flush_time")
-        self._decode_timing["flush_time"] = \
-            self._decode_timing.get("flush_time", 0.0) + elapsed
-        if tm is not None:
-            for rid in landed:
-                self._note_token(self.requests[rid])
-            tm.observe("flush_s", elapsed)
-            tm.complete("flush", c0, self.clock(),
-                        args={"tokens": len(landed)})
-        for rid, cut in poisoned.items():
-            req = self.requests.get(rid)
-            if req is None:
-                continue
-            del req.generated[cut:]       # placeholders only (never -1
-            self.stats["nan_rows"] += 1   # streamed, so emitted <= cut)
-            self._fail_request(rid, "nan_logits", flush=False)
+        return landed, poisoned
 
     # ------------------------------------------------------------------ #
     # eviction (evict phase) / reclamation
@@ -1350,27 +1369,29 @@ class DecodeEngine:
         User callbacks are ISOLATED: one raising ``on_token`` marks only
         that request FAILED (reason ``callback_error``) — the engine
         step, the batch, and every other stream are unaffected."""
-        for req in list(self.requests.values()):
-            if req.on_token is None:
-                continue
-            gen = req.generated
-            while req.emitted < len(gen) and gen[req.emitted] >= 0:
-                tok = gen[req.emitted]
-                req.emitted += 1
-                try:
-                    if self.injector is not None:
-                        spec = self.injector.take("callback", rid=req.rid)
-                        if spec is not None:
-                            self.stats["faults_injected"] += 1
-                            raise InjectedFault(
-                                spec, f"injected on_token failure for "
-                                      f"request {req.rid}")
-                    req.on_token(req.rid, tok)
-                except Exception:
-                    self.stats["callback_errors"] += 1
-                    self._fail_request(req.rid, "callback_error",
-                                       flush=False)
-                    break
+        with telemetry_mod.Span("codec.stream"):
+            for req in list(self.requests.values()):
+                if req.on_token is not None:
+                    self._stream_request(req)
+
+    def _stream_request(self, req: Request) -> None:
+        gen = req.generated
+        while req.emitted < len(gen) and gen[req.emitted] >= 0:
+            tok = gen[req.emitted]
+            req.emitted += 1
+            try:
+                if self.injector is not None:
+                    spec = self.injector.take("callback", rid=req.rid)
+                    if spec is not None:
+                        self.stats["faults_injected"] += 1
+                        raise InjectedFault(
+                            spec, f"injected on_token failure for "
+                                  f"request {req.rid}")
+                req.on_token(req.rid, tok)
+            except Exception:
+                self.stats["callback_errors"] += 1
+                self._fail_request(req.rid, "callback_error", flush=False)
+                break
 
     def _alloc_pages(self, n: int, exclude: Set[int],
                      allow_preempt: bool = True,
@@ -1608,13 +1629,15 @@ class DecodeEngine:
         req.last_tok_t = now
 
     def _prefill_step(self, req: Request, budget: Optional[int],
-                      stop_at: Optional[int] = None) -> int:
+                      stop_at: Optional[int] = None,
+                      shared_with: Sequence[int] = ()) -> int:
         """Advance the request's prefill by one chunk of ``<= budget``
         tokens (``None`` = the whole remaining prompt); returns tokens
         computed (0 = stalled on pages, retried next step).  ``stop_at``
         additionally caps the chunk at an absolute position — cascade
         phase A uses it to stop exactly at the group's shared-path
-        frontier (DESIGN.md §14).
+        frontier (DESIGN.md §14), and records the chunk on the
+        ``shared_with`` siblings' tracks too.
 
         Attention KV of the cached prefix is reused (gathered from the
         paged pool); SSM layers resume from the deepest cached boundary —
@@ -1624,10 +1647,8 @@ class DecodeEngine:
         When the sequence completes, the request joins the decode batch;
         ``pending`` is sampled only if it did not survive a preemption.
         """
-        cfg = self.cfg
         rid = req.rid
-        seq = req.seq
-        total = len(seq)
+        total = len(req.seq)
         path = self.forest.path(rid)
 
         # contiguous filled-KV front along the path
@@ -1681,7 +1702,35 @@ class DecodeEngine:
         if not self._ensure_pages_upto(rid, end):
             self.stats["prefill_stalls"] += 1
             return 0
-        c0 = self.clock() if self.telemetry is not None else 0.0
+        tm = self.telemetry
+        with telemetry_mod.PrefillSpan(self.stats, tm, track=rid,
+                                       args={"tokens": end - span_start}
+                                       ) as sp:
+            finished = self._prefill_chunk(req, path, span_start, end,
+                                           mamba_init)
+        Tn = end - span_start
+        if tm is not None:
+            for sib in shared_with:
+                tm.complete("prefill_chunk", sp.c0, sp.c1, track=sib,
+                            args={"tokens": Tn, "shared": True})
+            tm.observe("prefill_chunk_s", sp.c1 - sp.c0)
+        if finished:
+            # after the chunk span closes: the request's "prefill" span
+            # ends at promotion and must contain its chunks
+            self._promote(req)
+        return Tn
+
+    def _prefill_chunk(self, req: Request, path, span_start: int, end: int,
+                       mamba_init: Dict[int, Any]) -> bool:
+        """Compute positions ``[span_start, end)`` of the request's
+        sequence: attention reads the cached prefix's KV, new KV lands in
+        the unfilled page slots.  Samples ``pending`` when the sequence
+        completes; returns whether it did."""
+        cfg = self.cfg
+        rid = req.rid
+        seq = req.seq
+        total = len(seq)
+        has_mamba = any(k.mixer == "mamba" for k in self.layer_kinds)
 
         tokens = np.asarray(seq[span_start:end], np.int32)
         Tn = len(tokens)
@@ -1711,15 +1760,17 @@ class DecodeEngine:
                           else 0)
                 q, k_new, v_new = L.attn_project(p["attn"], cfg, h,
                                                  jnp.asarray(positions))
-                pk, pv = self._gather_prefix_upto(la, path, span_start)
-                k_all = jnp.concatenate([pk.astype(k_new.dtype)[None],
-                                         k_new], 1)
-                v_all = jnp.concatenate([pv.astype(v_new.dtype)[None],
-                                         v_new], 1)
-                o = L.mha(q, k_all, v_all, causal=True, window=window,
-                          softcap=cfg.attn_logit_softcap,
-                          q_positions=jnp.asarray(positions),
-                          kv_positions=jnp.arange(end)[None])
+                with telemetry_mod.Span("codec.prefill.gather"):
+                    pk, pv = self._gather_prefix_upto(la, path, span_start)
+                    k_all = jnp.concatenate([pk.astype(k_new.dtype)[None],
+                                             k_new], 1)
+                    v_all = jnp.concatenate([pv.astype(v_new.dtype)[None],
+                                             v_new], 1)
+                with telemetry_mod.Span("codec.prefill.attn"):
+                    o = L.mha(q, k_all, v_all, causal=True, window=window,
+                              softcap=cfg.attn_logit_softcap,
+                              q_positions=jnp.asarray(positions),
+                              kv_positions=jnp.arange(end)[None])
                 y = L.dense(p["attn"]["wo"],
                             o.reshape(1, Tn, cfg.num_heads * cfg.head_dim))
                 new_kv_writes.append((la, k_new[0], v_new[0]))
@@ -1743,54 +1794,50 @@ class DecodeEngine:
             x, _ = L.apply_ffn_block(p, cfg, kind.ffn, x)
 
         # write new KV into unfilled page slots only
-        offs, pages, kv_rows = [], [], []
-        ps = self.page_size
-        for node, lo, hi in segments:
-            start = node.meta.get("filled", 0)
-            base = node.start_pos - span_start   # span-local index of token 0
-            t_hi = hi - base
-            # a replicated node's KV lands in EVERY shard's replica run
-            # (same source row scattered to each), keeping replicas
-            # bitwise in sync with the primary
-            reps = node.meta.get("replicas")
-            page_lists = list(reps.values()) if reps else [node.page_ids]
-            for t in range(max(start, lo - base), t_hi):
-                for pl in page_lists:
-                    pages.append(pl[t // ps])
-                    offs.append(t % ps)
-                    kv_rows.append(base + t)
-            if t_hi > start:
-                node.meta["filled"] = t_hi
-        if kv_rows:
-            rows = jnp.asarray(np.asarray(kv_rows))
-            for la, k_new, v_new in new_kv_writes:
-                self.pool.write_tokens(la, np.asarray(pages),
-                                       np.asarray(offs),
-                                       k_new[rows], v_new[rows])
+        with telemetry_mod.Span("codec.prefill.kv_write"):
+            offs, pages, kv_rows = [], [], []
+            ps = self.page_size
+            for node, lo, hi in segments:
+                start = node.meta.get("filled", 0)
+                base = node.start_pos - span_start   # span-local token 0
+                t_hi = hi - base
+                # a replicated node's KV lands in EVERY shard's replica
+                # run (same source row scattered to each), keeping
+                # replicas bitwise in sync with the primary
+                reps = node.meta.get("replicas")
+                page_lists = (list(reps.values()) if reps
+                              else [node.page_ids])
+                for t in range(max(start, lo - base), t_hi):
+                    for pl in page_lists:
+                        pages.append(pl[t // ps])
+                        offs.append(t % ps)
+                        kv_rows.append(base + t)
+                if t_hi > start:
+                    node.meta["filled"] = t_hi
+            if kv_rows:
+                rows = jnp.asarray(np.asarray(kv_rows))
+                for la, k_new, v_new in new_kv_writes:
+                    self.pool.write_tokens(la, np.asarray(pages),
+                                           np.asarray(offs),
+                                           k_new[rows], v_new[rows])
 
         self.stats["prefill_tokens"] += Tn
         self.stats["recompute_tokens"] += max(
             0, min(end, req.computed_hwm) - span_start)
         req.computed_hwm = max(req.computed_hwm, end)
-        if self.telemetry is not None:
-            c1 = self.clock()
-            self.telemetry.complete("prefill_chunk", c0, c1, track=rid,
-                                    args={"tokens": Tn})
-            self.telemetry.observe("prefill_chunk_s", c1 - c0)
-
         if end < total:
             self.stats["prefill_chunks"] += 1
             if has_mamba:
                 self._mamba_pos[rid] = end
-            return Tn
+            return False
 
         if req.pending is None:
-            logits = T._unembed(self.params, cfg, x)[0, -1]
-            self.key, sk = jax.random.split(self.key)
-            req.pending = int(sampler.sample(logits[None], sk,
-                                             self.temperature)[0])
-        self._promote(req)
-        return Tn
+            with telemetry_mod.Span("codec.prefill.sample"):
+                logits = T._unembed(self.params, cfg, x)[0, -1]
+                self.key, sk = jax.random.split(self.key)
+                req.pending = int(sampler.sample(logits[None], sk,
+                                                 self.temperature)[0])
+        return True
 
     def _mamba_prefill(self, p, h, init):
         cfg = self.cfg
@@ -1867,9 +1914,17 @@ class DecodeEngine:
             np.int32(self._fused_delta), self._fused_prepared)
 
     def _rebuild_plans(self) -> None:
-        t0 = time.perf_counter()
-        c0 = self.clock() if self.telemetry is not None else 0.0
         rows = self._active_rows()
+        tm = self.telemetry
+        with telemetry_mod.Span("codec.plan_build", tm, "plan_build",
+                                args={"rows": len(rows)}) as sp:
+            self._build_plans(rows)
+        self.stats["replans"] += 1
+        self.stats["plan_time"] += sp.seconds
+        if tm is not None:
+            tm.observe("plan_build_s", sp.c1 - sp.c0)
+
+    def _build_plans(self, rows: List[int]) -> None:
         req_rows = {r: i for i, r in enumerate(rows)}
         ps = self.page_size
         truncate = {}
@@ -1890,13 +1945,6 @@ class DecodeEngine:
         self._plan_key = plan_mod.plan_key(self.forest, rows)
         self._plan_dirty = False
         self._steps_since_plan = 0
-        self.stats["replans"] += 1
-        self.stats["plan_time"] += time.perf_counter() - t0
-        if self.telemetry is not None:
-            c1 = self.clock()
-            self.telemetry.complete("plan_build", c0, c1,
-                                    args={"rows": len(rows)})
-            self.telemetry.observe("plan_build_s", c1 - c0)
 
     def _advance_qpos(self) -> None:
         """Cheap per-step plan refresh: live queries moved one position."""
@@ -1914,6 +1962,17 @@ class DecodeEngine:
         pending tokens (evicting under pressure) and decode one token per
         running request."""
         tm = self.telemetry
+        n = len(self.step_stats)
+        with telemetry_mod.Span("codec.step", tm, "step", args={"step": n},
+                                step=n) as sp:
+            out = self._step()
+            sp.args["decoded"] = len(out)
+        if tm is not None:
+            tm.observe("step_s", sp.c1 - sp.c0)
+        return out
+
+    def _step(self) -> Dict[int, int]:
+        tm = self.telemetry
         snap = {k: self.stats[k]
                 for k in ("steps", "admitted", "preempted", "reclaimed",
                           "prefill_tokens", "recompute_tokens",
@@ -1925,9 +1984,6 @@ class DecodeEngine:
         # enforcement or between-step admission bills this step's
         # flush_time, never the decode phase's dispatch/compute split
         self._decode_timing = {}
-        if tm is not None:
-            c_step0 = self.clock()
-            tm.begin("step", args={"step": len(self.step_stats)})
         if self.injector is not None:
             self.injector.tick(len(self.step_stats))
         self._enforce_deadlines()
@@ -1996,9 +2052,6 @@ class DecodeEngine:
                 tm.observe("profile_device_s", t.get("compute_time", 0.0))
                 tm.observe("profile_host_s", t.get("host_time", 0.0))
             self._publish_telemetry()
-            c_step1 = self.clock()
-            tm.observe("step_s", c_step1 - c_step0)
-            tm.end(args={"decoded": len(out)})            # "step"
         return out
 
     def _cache_step_delta(self) -> Dict[str, int]:
@@ -2180,125 +2233,123 @@ class DecodeEngine:
         rows0 = self._active_rows()
         if not rows0:
             return {}
-        t0 = time.perf_counter()
-        c0 = self.clock() if self.telemetry is not None else 0.0
-        flush_before = self._decode_timing.get("flush_time", 0.0)
-        # 1. append pending tokens to leaves (may evict under pressure)
-        self._append_pending(rows0)
-        rows = self._active_rows()
-        if not rows:
-            return {}
-        tokens = [self.requests[r].generated[-1] for r in rows]
+        with telemetry_mod.Span("codec.decode", self.telemetry,
+                                "decode") as sp:
+            flush_before = self._decode_timing.get("flush_time", 0.0)
+            # 1. append pending tokens to leaves (may evict under pressure)
+            self._append_pending(rows0)
+            rows = self._active_rows()
+            if not rows:
+                sp.event = None                 # nothing decoded: no event
+                return {}
+            tokens = [self.requests[r].generated[-1] for r in rows]
 
-        # 2. plan lifecycle: rebuild exactly when the plan key changed
-        #    (membership, path structure, tail page) or on the interval
-        if (self.replan_interval is not None
-                and self._steps_since_plan >= self.replan_interval):
-            self._plan_dirty = True
-        if (self._plan_dirty
-                or plan_mod.plan_key(self.forest, rows) != self._plan_key):
-            self._rebuild_plans()
-        else:
-            self._advance_qpos()
-        self._steps_since_plan += 1
+            # 2. plan lifecycle: rebuild exactly when the plan key changed
+            #    (membership, path structure, tail page) or on the interval
+            if (self.replan_interval is not None
+                    and self._steps_since_plan >= self.replan_interval):
+                self._plan_dirty = True
+            if (self._plan_dirty
+                    or plan_mod.plan_key(self.forest, rows) != self._plan_key):
+                self._rebuild_plans()
+            else:
+                self._advance_qpos()
+            self._steps_since_plan += 1
 
-        B = len(rows)
-        ctx = np.array([self.forest.context_len(r) for r in rows], np.int32)
-        q_pos = jnp.asarray(ctx - 1)
-        x = T._embed(self.params, cfg, jnp.asarray(tokens)[None].T,
-                     q_pos[:, None])                       # (B,1,d)
+            B = len(rows)
+            ctx = np.array([self.forest.context_len(r) for r in rows],
+                           np.int32)
+            q_pos = jnp.asarray(ctx - 1)
+            x = T._embed(self.params, cfg, jnp.asarray(tokens)[None].T,
+                         q_pos[:, None])                       # (B,1,d)
 
-        # tail page info, converted host->device ONCE per step (not once
-        # per attention layer)
-        tail_pages, tail_base, tail_off = [], [], []
-        for i, r in enumerate(rows):
-            leaf = self.forest.nodes[self.forest.leaf_of[r]]
-            tp = (leaf.length - 1) // self.page_size
-            tail_pages.append(leaf.page_ids[tp])
-            tail_base.append(leaf.start_pos + tp * self.page_size)
-            tail_off.append((leaf.length - 1) % self.page_size)
-        tail_pages = jnp.asarray(np.asarray(tail_pages), jnp.int32)
-        tail_base = jnp.asarray(np.asarray(tail_base), jnp.int32)
-        tail_off = jnp.asarray(np.asarray(tail_off), jnp.int32)
+            # tail page info, converted host->device ONCE per step (not once
+            # per attention layer)
+            tail_pages, tail_base, tail_off = [], [], []
+            for i, r in enumerate(rows):
+                leaf = self.forest.nodes[self.forest.leaf_of[r]]
+                tp = (leaf.length - 1) // self.page_size
+                tail_pages.append(leaf.page_ids[tp])
+                tail_base.append(leaf.start_pos + tp * self.page_size)
+                tail_off.append((leaf.length - 1) % self.page_size)
+            tail_pages = jnp.asarray(np.asarray(tail_pages), jnp.int32)
+            tail_base = jnp.asarray(np.asarray(tail_base), jnp.int32)
+            tail_off = jnp.asarray(np.asarray(tail_off), jnp.int32)
 
-        for j, kind in enumerate(self.layer_kinds):
-            p = layer_params(cfg, self.params, j)
-            h = L.apply_norm(p["ln"], x, cfg)
-            if kind.mixer in ("attn", "attn_local"):
-                la = self.attn_layer_idx[j]
-                window = (cfg.sliding_window if kind.mixer == "attn_local"
-                          else 0)
-                q, k_new, v_new = L.attn_project(p["attn"], cfg, h,
-                                                 q_pos[:, None])
-                self.pool.write_tokens(la, tail_pages, tail_off,
-                                       k_new[:, 0], v_new[:, 0])
-                k_pool, v_pool = self.pool.layer_pools(la)
-                qb = q[:, 0]                                # (B, h, hd)
-                o = self._attend(qb, k_pool, v_pool, window, B,
-                                 tail_pages, tail_base, q_pos)
-                y = L.dense(p["attn"]["wo"],
-                            o.reshape(B, 1, cfg.num_heads * cfg.head_dim))
-                x = x + y
-            elif kind.mixer == "mamba":
-                states = self.mamba_state[j]
-                conv = jnp.concatenate([states[r][0] for r in rows], 0)
-                ssm = jnp.concatenate([states[r][1] for r in rows], 0)
-                y, (conv_n, ssm_n) = M.mamba_decode(p["mamba"], cfg, h,
-                                                    conv, ssm)
-                for i, r in enumerate(rows):
-                    states[r] = (conv_n[i:i + 1], ssm_n[i:i + 1])
-                x = x + y
-            x, _ = L.apply_ffn_block(p, cfg, kind.ffn, x)
+            for j, kind in enumerate(self.layer_kinds):
+                p = layer_params(cfg, self.params, j)
+                h = L.apply_norm(p["ln"], x, cfg)
+                if kind.mixer in ("attn", "attn_local"):
+                    la = self.attn_layer_idx[j]
+                    window = (cfg.sliding_window if kind.mixer == "attn_local"
+                              else 0)
+                    q, k_new, v_new = L.attn_project(p["attn"], cfg, h,
+                                                     q_pos[:, None])
+                    self.pool.write_tokens(la, tail_pages, tail_off,
+                                           k_new[:, 0], v_new[:, 0])
+                    k_pool, v_pool = self.pool.layer_pools(la)
+                    qb = q[:, 0]                                # (B, h, hd)
+                    o = self._attend(qb, k_pool, v_pool, window, B,
+                                     tail_pages, tail_base, q_pos)
+                    y = L.dense(p["attn"]["wo"],
+                                o.reshape(B, 1, cfg.num_heads * cfg.head_dim))
+                    x = x + y
+                elif kind.mixer == "mamba":
+                    states = self.mamba_state[j]
+                    conv = jnp.concatenate([states[r][0] for r in rows], 0)
+                    ssm = jnp.concatenate([states[r][1] for r in rows], 0)
+                    y, (conv_n, ssm_n) = M.mamba_decode(p["mamba"], cfg, h,
+                                                        conv, ssm)
+                    for i, r in enumerate(rows):
+                        states[r] = (conv_n[i:i + 1], ssm_n[i:i + 1])
+                    x = x + y
+                x, _ = L.apply_ffn_block(p, cfg, kind.ffn, x)
 
-        logits = T._unembed(self.params, cfg, x)[:, 0]      # (B, V)
-        if self.injector is not None:
-            spec = self.injector.take("nan_logits")
-            if spec is not None:
-                target = spec.rid if spec.rid in rows else rows[0]
-                logits = logits.at[rows.index(target)].set(jnp.nan)
-                self.stats["faults_injected"] += 1
-        self.key, sk = jax.random.split(self.key)
-        toks_dev = sampler.sample(logits, sk, self.temperature)
-        t1 = time.perf_counter()
-        # dispatch is done; the timer must cover the actual compute too
-        toks = np.asarray(jax.block_until_ready(toks_dev))
-        t2 = time.perf_counter()
-        bad_rows: List[int] = []
-        if self.nan_guard:
-            # the eager path syncs every step anyway, so a host-side
-            # finite check costs one extra small transfer
-            okv = np.asarray(jnp.isfinite(logits).all(-1))
-            bad_rows = [r for i, r in enumerate(rows) if not okv[i]]
-        out = {}
-        for i, r in enumerate(rows):
-            if r in bad_rows:
-                continue
-            req = self.requests[r]
-            req.pending = int(toks[i])
-            req.computed_hwm = max(req.computed_hwm, int(ctx[i]))
-            out[r] = int(toks[i])
-            if len(req.generated) >= req.max_new:
-                req.state = DONE
-        for r in bad_rows:
-            # quarantine: the poisoned token never enters the stream,
-            # the batch keeps decoding without the failed row
-            self.stats["nan_rows"] += 1
-            self._fail_request(r, "nan_logits", flush=False)
-        self.stats["steps"] += 1
-        # any flush that ran inside this phase (preempting appends) has
-        # billed flush_time already; keep it out of the dispatch split
-        flush_in = self._decode_timing.get("flush_time", 0.0) \
-            - flush_before
-        self._decode_timing.update(
-            dispatch_time=max(0.0, t1 - t0 - flush_in),
-            compute_time=t2 - t1)
-        self.stats["decode_dispatch_time"] += \
-            self._decode_timing["dispatch_time"]
-        self.stats["decode_time"] += time.perf_counter() - t0
-        if self.telemetry is not None:
-            self.telemetry.complete("decode", c0, self.clock(),
-                                    args={"mode": "eager",
-                                          "rows": len(rows)})
+            logits = T._unembed(self.params, cfg, x)[:, 0]      # (B, V)
+            if self.injector is not None:
+                spec = self.injector.take("nan_logits")
+                if spec is not None:
+                    target = spec.rid if spec.rid in rows else rows[0]
+                    logits = logits.at[rows.index(target)].set(jnp.nan)
+                    self.stats["faults_injected"] += 1
+            self.key, sk = jax.random.split(self.key)
+            toks_dev = sampler.sample(logits, sk, self.temperature)
+            t1 = time.perf_counter()
+            # dispatch is done; the timer must cover the actual compute too
+            toks = np.asarray(jax.block_until_ready(toks_dev))
+            t2 = time.perf_counter()
+            bad_rows: List[int] = []
+            if self.nan_guard:
+                # the eager path syncs every step anyway, so a host-side
+                # finite check costs one extra small transfer
+                okv = np.asarray(jnp.isfinite(logits).all(-1))
+                bad_rows = [r for i, r in enumerate(rows) if not okv[i]]
+            out = {}
+            for i, r in enumerate(rows):
+                if r in bad_rows:
+                    continue
+                req = self.requests[r]
+                req.pending = int(toks[i])
+                req.computed_hwm = max(req.computed_hwm, int(ctx[i]))
+                out[r] = int(toks[i])
+                if len(req.generated) >= req.max_new:
+                    req.state = DONE
+            for r in bad_rows:
+                # quarantine: the poisoned token never enters the stream,
+                # the batch keeps decoding without the failed row
+                self.stats["nan_rows"] += 1
+                self._fail_request(r, "nan_logits", flush=False)
+            self.stats["steps"] += 1
+            # any flush that ran inside this phase (preempting appends) has
+            # billed flush_time already; keep it out of the dispatch split
+            flush_in = self._decode_timing.get("flush_time", 0.0) \
+                - flush_before
+            self._decode_timing.update(
+                dispatch_time=max(0.0, t1 - sp.t0 - flush_in),
+                compute_time=t2 - t1)
+            self.stats["decode_dispatch_time"] += \
+                self._decode_timing["dispatch_time"]
+            sp.args = {"mode": "eager", "rows": len(rows)}
         return out
 
     def _attend(self, qb, k_pool, v_pool, window, B,
@@ -2324,151 +2375,157 @@ class DecodeEngine:
         rows0 = self._active_rows()
         if not rows0:
             return {}
-        t0 = time.perf_counter()
         tm = self.telemetry
-        c0 = self.clock() if tm is not None else 0.0
-        flush_before = self._decode_timing.get("flush_time", 0.0)
-        # sampled profiling (telemetry.profile_every): this step blocks
-        # on the device to split dispatch/device/host phases; unsampled
-        # steps stay on the async fast path untouched
-        profiled = tm is not None \
-            and tm.should_profile(len(self.step_stats))
-        # 1. append pending tokens (host ints after a sync / prefill,
-        #    otherwise the in-flight device array via placeholders)
-        self._append_pending(rows0)
-        rows = self._active_rows()
-        if not rows:
-            return {}
+        with telemetry_mod.Span("codec.decode", tm, "decode") as sp:
+            flush_before = self._decode_timing.get("flush_time", 0.0)
+            # sampled profiling (telemetry.profile_every): this step blocks
+            # on the device to split dispatch/device/host phases; unsampled
+            # steps stay on the async fast path untouched
+            profiled = tm is not None \
+                and tm.should_profile(len(self.step_stats))
+            # 1. append pending tokens (host ints after a sync / prefill,
+            #    otherwise the in-flight device array via placeholders)
+            self._append_pending(rows0)
+            rows = self._active_rows()
+            if not rows:
+                sp.event = None                 # nothing decoded: no event
+                return {}
 
-        # 2. plan lifecycle: a rebuild is the sync point — deferred
-        #    tokens land, batched SSM state scatters back, plans/base
-        #    arrays are rebuilt bucketed
-        if (self.replan_interval is not None
-                and self._steps_since_plan >= self.replan_interval):
-            self._plan_dirty = True
-        if (self._plan_dirty or self._fused_rows != rows
-                or plan_mod.plan_key(self.forest, rows) != self._plan_key):
-            self._fused_epoch(rows)
-        else:
-            self._fused_delta += 1
-        self._steps_since_plan += 1
+            # 2. plan lifecycle: a rebuild is the sync point — deferred
+            #    tokens land, batched SSM state scatters back, plans/base
+            #    arrays are rebuilt bucketed
+            if (self.replan_interval is not None
+                    and self._steps_since_plan >= self.replan_interval):
+                self._plan_dirty = True
+            if (self._plan_dirty or self._fused_rows != rows
+                    or plan_mod.plan_key(self.forest, rows) != self._plan_key):
+                self._fused_epoch(rows)
+            else:
+                self._fused_delta += 1
+            self._steps_since_plan += 1
 
-        # 3. input tokens: in steady state the previous dispatch's device
-        #    array (no host round-trip); after any sync, host values
-        if (not self._flushed_since_dispatch and self._last_out is not None
-                and self._last_out[0] == rows):
-            tok_in = self._last_out[1]
-        else:
-            tok = np.zeros(self._fused_bucket, np.int32)
-            tok[:len(rows)] = [self.requests[r].generated[-1] for r in rows]
-            tok_in = jnp.asarray(tok)
-            if self._replicated_sharding is not None:
-                tok_in = jax.device_put(tok_in, self._replicated_sharding)
+            # 3. input tokens: in steady state the previous dispatch's device
+            #    array (no host round-trip); after any sync, host values
+            if (not self._flushed_since_dispatch and self._last_out is not None
+                    and self._last_out[0] == rows):
+                tok_in = self._last_out[1]
+            else:
+                tok = np.zeros(self._fused_bucket, np.int32)
+                tok[:len(rows)] = [self.requests[r].generated[-1]
+                                   for r in rows]
+                tok_in = jnp.asarray(tok)
+                if self._replicated_sharding is not None:
+                    tok_in = jax.device_put(tok_in, self._replicated_sharding)
 
-        # injected NaN: corrupt one KV slot of the target's PRIVATE leaf
-        # so the dispatch's attention reads it, poisons that row's
-        # logits, and the row_ok flag catches it at the next flush —
-        # exercising the real corruption path, not a shortcut
-        if self.injector is not None:
-            spec = self.injector.take("nan_logits")
-            if spec is not None:
-                target = spec.rid if spec.rid in rows else rows[0]
-                leaf = self.forest.nodes[self.forest.leaf_of[target]]
-                if (leaf.length >= 2 and len(leaf.requests) == 1
-                        and not leaf.children):
-                    slot = leaf.length - 2
-                    page = leaf.page_ids[slot // self.page_size]
-                    off = slot % self.page_size
-                    self.pool.k = self.pool.k.at[:, page, off].set(
-                        jnp.nan)
-                    self._nan_dirty.append((page, off))
-                    self.stats["faults_injected"] += 1
-                else:       # leaf shared or too short: try again later
-                    self.injector.requeue(spec)
+            # injected NaN: corrupt one KV slot of the target's PRIVATE leaf
+            # so the dispatch's attention reads it, poisons that row's
+            # logits, and the row_ok flag catches it at the next flush —
+            # exercising the real corruption path, not a shortcut
+            if self.injector is not None:
+                spec = self.injector.take("nan_logits")
+                if spec is not None:
+                    target = spec.rid if spec.rid in rows else rows[0]
+                    leaf = self.forest.nodes[self.forest.leaf_of[target]]
+                    if (leaf.length >= 2 and len(leaf.requests) == 1
+                            and not leaf.children):
+                        slot = leaf.length - 2
+                        page = leaf.page_ids[slot // self.page_size]
+                        off = slot % self.page_size
+                        self.pool.k = self.pool.k.at[:, page, off].set(
+                            jnp.nan)
+                        self._nan_dirty.append((page, off))
+                        self.stats["faults_injected"] += 1
+                    else:       # leaf shared or too short: try again later
+                        self.injector.requeue(spec)
 
-        # 4. single dispatch: layers + KV writes + attention + merge +
-        #    FFN + unembed + sampling, pool/SSM state donated
-        conv_all, ssm_all = self._mamba_carry
-        state = step_fn_mod.StepState(self.pool.k, self.pool.v,
-                                      conv_all, ssm_all)
-        t_d0 = time.perf_counter()
-        if self.mesh is not None:
-            # the sharded step fn has no row_ok output (nan_guard is
-            # rejected with a mesh at construction)
-            toks_dev, self.key, state = self._step_fn(
-                self.params, state, tok_in, self.key, self._fused_base,
-                np.int32(self._fused_delta), self._fused_prepared)
-            ok_dev = None
-        else:
-            toks_dev, ok_dev, self.key, state = self._step_fn(
-                self.params, state, tok_in, self.key, self._fused_base,
-                np.int32(self._fused_delta), self._fused_prepared)
-        t_d1 = time.perf_counter()
-        calibrating = self.calibrate and self.mesh is not None
-        if calibrating or profiled:
-            # calibration/profiling fit against TRUE step seconds, so
-            # the async dispatch must block here (costs the overlap;
-            # opt-in — calibrate blocks every step, profile_every only
-            # the sampled ones)
-            jax.block_until_ready(toks_dev)
-        t_d2 = time.perf_counter()
-        # calibrate keeps its historical meaning: dispatch_time is the
-        # full blocked step.  Profiled steps split submit vs device.
-        dispatch = (t_d2 if calibrating else t_d1) - t_d0
-        self.pool.k, self.pool.v = state.pool_k, state.pool_v
-        self._mamba_carry = (state.conv, state.ssm)
-        ent = _Deferred(toks_dev, list(rows),
-                        ok=ok_dev if self.nan_guard else None)
-        self._deferred.append(ent)
-        self._last_out = (list(rows), toks_dev)
-        self._flushed_since_dispatch = False
-        out: Dict[int, Optional[int]] = {}
-        done_any = False
-        for i, r in enumerate(rows):
-            req = self.requests[r]
-            req.pending = PENDING_DEVICE
-            self._pending_ref[r] = (ent, i)
-            req.computed_hwm = max(req.computed_hwm,
-                                   self.forest.context_len(r))
-            out[r] = None
-            if len(req.generated) >= req.max_new:
-                req.state = DONE
-                done_any = True
-        self.stats["steps"] += 1
-        self.stats["fused_calls"] += 1
-        self.stats["decode_dispatch_time"] += dispatch
-        flush_in = self._decode_timing.get("flush_time", 0.0) \
-            - flush_before
-        self._decode_timing.update(dispatch_time=dispatch)
-        if profiled and not calibrating:
-            self._decode_timing.update(
-                compute_time=t_d2 - t_d1, profiled=True,
-                host_time=max(0.0, t_d0 - t0 - flush_in))
-        if self.mesh is not None and self._epoch_features:
-            self._decode_timing.update(self._epoch_features)
-            if profiled and self._epoch_shard_features:
-                # per-shard attribution of the sampled step (feeds
-                # CostModel.fit / imbalance analysis downstream)
-                self._decode_timing.update(self._epoch_shard_features)
-        if done_any:
-            # completion boundary: finished streams must be readable
-            self.flush_tokens()
-            for r in rows:
-                if self.requests[r].done:
-                    out[r] = self.requests[r].generated[-1]
-        self.stats["decode_time"] += time.perf_counter() - t0
-        if tm is not None:
-            tm.complete("decode", c0, self.clock(),
-                        args={"mode": "fused", "rows": len(rows),
-                              "profiled": bool(profiled)})
+            # 4. single dispatch: layers + KV writes + attention + merge +
+            #    FFN + unembed + sampling, pool/SSM state donated
+            conv_all, ssm_all = self._mamba_carry
+            state = step_fn_mod.StepState(self.pool.k, self.pool.v,
+                                          conv_all, ssm_all)
+            with telemetry_mod.Span("codec.dispatch") as sd:
+                if self.mesh is not None:
+                    # the sharded step fn has no row_ok output (nan_guard is
+                    # rejected with a mesh at construction)
+                    toks_dev, self.key, state = self._step_fn(
+                        self.params, state, tok_in, self.key, self._fused_base,
+                        np.int32(self._fused_delta), self._fused_prepared)
+                    ok_dev = None
+                else:
+                    toks_dev, ok_dev, self.key, state = self._step_fn(
+                        self.params, state, tok_in, self.key, self._fused_base,
+                        np.int32(self._fused_delta), self._fused_prepared)
+            calibrating = self.calibrate and self.mesh is not None
+            t_d2 = sd.t1
+            if calibrating or profiled:
+                # calibration/profiling fit against TRUE step seconds, so
+                # the async dispatch must block here (costs the overlap;
+                # opt-in — calibrate blocks every step, profile_every only
+                # the sampled ones)
+                jax.block_until_ready(toks_dev)
+                t_d2 = time.perf_counter()
+            # calibrate keeps its historical meaning: dispatch_time is the
+            # full blocked step.  Profiled steps split submit vs device.
+            dispatch = (t_d2 if calibrating else sd.t1) - sd.t0
+            self.pool.k, self.pool.v = state.pool_k, state.pool_v
+            self._mamba_carry = (state.conv, state.ssm)
+            ent = _Deferred(toks_dev, list(rows),
+                            ok=ok_dev if self.nan_guard else None)
+            self._deferred.append(ent)
+            self._last_out = (list(rows), toks_dev)
+            self._flushed_since_dispatch = False
+            out: Dict[int, Optional[int]] = {}
+            done_any = False
+            for i, r in enumerate(rows):
+                req = self.requests[r]
+                req.pending = PENDING_DEVICE
+                self._pending_ref[r] = (ent, i)
+                req.computed_hwm = max(req.computed_hwm,
+                                       self.forest.context_len(r))
+                out[r] = None
+                if len(req.generated) >= req.max_new:
+                    req.state = DONE
+                    done_any = True
+            self.stats["steps"] += 1
+            self.stats["fused_calls"] += 1
+            self.stats["decode_dispatch_time"] += dispatch
+            flush_in = self._decode_timing.get("flush_time", 0.0) \
+                - flush_before
+            self._decode_timing.update(dispatch_time=dispatch)
+            if profiled and not calibrating:
+                self._decode_timing.update(
+                    compute_time=t_d2 - sd.t1, profiled=True,
+                    host_time=max(0.0, sd.t0 - sp.t0 - flush_in))
+            if self.mesh is not None and self._epoch_features:
+                self._decode_timing.update(self._epoch_features)
+                if profiled and self._epoch_shard_features:
+                    # per-shard attribution of the sampled step (feeds
+                    # CostModel.fit / imbalance analysis downstream)
+                    self._decode_timing.update(self._epoch_shard_features)
+            if done_any:
+                # completion boundary: finished streams must be readable
+                self.flush_tokens()
+                for r in rows:
+                    if self.requests[r].done:
+                        out[r] = self.requests[r].generated[-1]
+            sp.args = {"mode": "fused", "rows": len(rows),
+                       "profiled": bool(profiled)}
         return out
 
     def _fused_epoch(self, rows: List[int]) -> None:
         """Start a new plan epoch (the fused path's only sync point)."""
         self.flush_tokens()
         self._sync_mamba_state()
-        t0 = time.perf_counter()
-        c0 = self.clock() if self.telemetry is not None else 0.0
+        tm = self.telemetry
+        with telemetry_mod.Span("codec.plan_build", tm, "plan_build") as sp:
+            self._build_epoch(rows)
+            sp.args = {"rows": len(rows), "bucket": self._fused_bucket}
+        self.stats["replans"] += 1
+        self.stats["plan_time"] += sp.seconds
+        if tm is not None:
+            tm.observe("plan_build_s", sp.c1 - sp.c0)
+
+    def _build_epoch(self, rows: List[int]) -> None:
         B = len(rows)
         bucket = plan_mod.bucket_pow2(B)
         req_rows = {r: i for i, r in enumerate(rows)}
@@ -2521,14 +2578,6 @@ class DecodeEngine:
         self._plan_key = plan_mod.plan_key(self.forest, rows)
         self._plan_dirty = False
         self._steps_since_plan = 0
-        self.stats["replans"] += 1
-        self.stats["plan_time"] += time.perf_counter() - t0
-        if self.telemetry is not None:
-            c1 = self.clock()
-            self.telemetry.complete("plan_build", c0, c1,
-                                    args={"rows": len(rows),
-                                          "bucket": self._fused_bucket})
-            self.telemetry.observe("plan_build_s", c1 - c0)
 
     def _sharded_epoch(self, rows: List[int], bucket: int,
                        req_rows: Dict[int, int],
@@ -2853,19 +2902,17 @@ class DecodeEngine:
         rows0 = self._active_rows()
         if not rows0:
             return {}
-        t0 = time.perf_counter()
         tm = self.telemetry
         self._append_pending(rows0)        # host ints: spec never defers
         rows = self._active_rows()
         if not rows:
             return {}
-        c0 = self.clock() if tm is not None else 0.0
-        self._grow_drafts(rows)
-        if tm is not None:
-            tm.complete("spec_propose", c0, self.clock(),
-                        args={"rows": len(rows),
-                              "drafts": sum(len(st.nodes) for st in
-                                            self._drafts.values())})
+        with telemetry_mod.Span("codec.spec_propose", tm,
+                                "spec_propose") as sp:
+            self._grow_drafts(rows)
+            sp.args = {"rows": len(rows),
+                       "drafts": sum(len(st.nodes)
+                                     for st in self._drafts.values())}
         # injected NaN: poison a committed KV slot of the target's leaf
         # (as in the fused path) so every verify row of that request —
         # base query and draft heads — reads it through the verify plan
@@ -2889,28 +2936,19 @@ class DecodeEngine:
                     self.injector.requeue(spec)
         tokens, q_pos, w_page, w_off, req_rows, head_rows = \
             self._spec_layout(rows)
-        tp0 = time.perf_counter()
-        plans = {}
-        for w in self._windows():
-            p = plan_mod.build_verify_plan(
+        with telemetry_mod.Span("codec.plan_build") as sp:
+            plans = {w: plan_mod.build_verify_plan(
                 self.forest, self.cost_model, req_rows, self.num_lanes,
                 self.max_q, self.max_kv_per_task, window=w,
-                kind=self._backend.plan_kind)
-            plans[w] = p
+                kind=self._backend.plan_kind) for w in self._windows()}
         self.stats["replans"] += 1
-        self.stats["plan_time"] += time.perf_counter() - tp0
-        t_d0 = time.perf_counter()
-        c_v0 = self.clock() if tm is not None else 0.0
-        if self._spec_step_fn is not None:
-            toks, ok = self._spec_verify_fused(tokens, q_pos, w_page,
-                                               w_off, plans)
-        else:
-            toks, ok = self._spec_verify_eager(tokens, q_pos, w_page,
-                                               w_off, plans)
-        t_d1 = time.perf_counter()
-        if tm is not None:
-            tm.complete("spec_verify", c_v0, self.clock(),
-                        args={"queries": len(tokens)})
+        self.stats["plan_time"] += sp.seconds
+        with telemetry_mod.Span("codec.spec_verify", tm, "spec_verify",
+                                args={"queries": len(tokens)}) as sv:
+            verify = (self._spec_verify_fused
+                      if self._spec_step_fn is not None
+                      else self._spec_verify_eager)
+            toks, ok = verify(tokens, q_pos, w_page, w_off, plans)
         if self.nan_guard:
             # quarantine before commit: a poisoned request's drafts roll
             # back with it and nothing enters its committed stream
@@ -2918,19 +2956,16 @@ class DecodeEngine:
                 if not bool(ok[req_rows[r]]):
                     self.stats["nan_rows"] += 1
                     self._fail_request(r, "nan_logits", flush=False)
-        c_a0 = self.clock() if tm is not None else 0.0
-        out = self._spec_commit(rows, toks, head_rows)
+        with telemetry_mod.Span("codec.spec_accept", tm,
+                                "spec_accept") as sa:
+            out = self._spec_commit(rows, toks, head_rows)
+            sa.args = {"accepted": sum(1 for v in out.values()
+                                       if v is not None)}
         self.stats["steps"] += 1
         self.stats["spec_steps"] += 1
-        self._decode_timing.update(
-            dispatch_time=t_d1 - t_d0,
-            compute_time=time.perf_counter() - t_d1)
-        self.stats["decode_dispatch_time"] += t_d1 - t_d0
-        self.stats["decode_time"] += time.perf_counter() - t0
-        if tm is not None:
-            tm.complete("spec_accept", c_a0, self.clock(),
-                        args={"accepted": sum(
-                            1 for v in out.values() if v is not None)})
+        self._decode_timing.update(dispatch_time=sv.seconds,
+                                   compute_time=sa.t1 - sv.t1)
+        self.stats["decode_dispatch_time"] += sv.seconds
         return out
 
     def _spec_verify_eager(self, tokens, q_pos, w_page, w_off,
@@ -3212,14 +3247,15 @@ class DecodeEngine:
     def _notify_done(self) -> None:
         """Fire ``on_done`` for normally-completed requests whose stream
         has fully drained (failure states notify inside ``_finish``)."""
-        for req in list(self.requests.values()):
-            if req.state != DONE or req.notified:
-                continue
-            if req.on_token is not None and req.emitted < len(
-                    req.generated):
-                continue    # tokens still deferred: next boundary
-            req.finish_reason = req.finish_reason or "done"
-            self._fire_on_done(req)
+        with telemetry_mod.Span("codec.stream"):
+            for req in list(self.requests.values()):
+                if req.state != DONE or req.notified:
+                    continue
+                if req.on_token is not None and req.emitted < len(
+                        req.generated):
+                    continue    # tokens still deferred: next boundary
+                req.finish_reason = req.finish_reason or "done"
+                self._fire_on_done(req)
 
     def _enforce_deadlines(self) -> None:
         """Step-boundary deadline sweep over every pre-terminal request

@@ -204,20 +204,25 @@ def make_step_fn(cfg: ModelConfig, backend, windows: Tuple[int, ...],
                 w = cfg.sliding_window if kind.mixer == "attn_local" else 0
                 q, k_new, v_new = L.attn_project(p["attn"], cfg, h,
                                                  q_pos[:, None])
-                pool_k = pool_k.at[la, base.tail_page, tail_off].set(
-                    k_new[:, 0].astype(pool_k.dtype))
-                pool_v = pool_v.at[la, base.tail_page, tail_off].set(
-                    v_new[:, 0].astype(pool_v.dtype))
-                k_pool, v_pool = pool_k[la], pool_v[la]
+                with jax.named_scope("codec.kv_write"):
+                    pool_k = pool_k.at[la, base.tail_page, tail_off].set(
+                        k_new[:, 0].astype(pool_k.dtype))
+                    pool_v = pool_v.at[la, base.tail_page, tail_off].set(
+                        v_new[:, 0].astype(pool_v.dtype))
+                with jax.named_scope("codec.pool_slice"):
+                    k_pool, v_pool = pool_k[la], pool_v[la]
                 qb = q[:, 0]                                # (B, h, hd)
-                o_f, m_f, l_f = backend.partials_arrays_fn(
-                    qb, k_pool, v_pool, advanced[win_slot[w]],
-                    num_queries=B, window=w)
-                kt = k_pool[base.tail_page]
-                vt = v_pool[base.tail_page]
-                o_t, m_t, l_t = ops.single_page_attention(
-                    qb, kt, vt, base.tail_base, q_pos, window=w)
-                o, _, _ = ref_mod.por_ref(o_f, m_f, l_f, o_t, m_t, l_t)
+                with jax.named_scope("codec.attn"):
+                    o_f, m_f, l_f = backend.partials_arrays_fn(
+                        qb, k_pool, v_pool, advanced[win_slot[w]],
+                        num_queries=B, window=w)
+                with jax.named_scope("codec.tail_page"):
+                    kt = k_pool[base.tail_page]
+                    vt = v_pool[base.tail_page]
+                    o_t, m_t, l_t = ops.single_page_attention(
+                        qb, kt, vt, base.tail_base, q_pos, window=w)
+                with jax.named_scope("codec.por_merge"):
+                    o, _, _ = ref_mod.por_ref(o_f, m_f, l_f, o_t, m_t, l_t)
                 y = L.dense(p["attn"]["wo"],
                             o.astype(qb.dtype).reshape(
                                 B, 1, cfg.num_heads * cfg.head_dim))
@@ -228,7 +233,8 @@ def make_step_fn(cfg: ModelConfig, backend, windows: Tuple[int, ...],
                 conv_all = conv_all.at[lm].set(conv_n)
                 ssm_all = ssm_all.at[lm].set(ssm_n)
                 x = x + y
-            x, _ = L.apply_ffn_block(p, cfg, kind.ffn, x)
+            with jax.named_scope("codec.ffn"):
+                x, _ = L.apply_ffn_block(p, cfg, kind.ffn, x)
             return (x, pool_k, pool_v, conv_all, ssm_all)
 
         x, pool_k, pool_v, conv_all, ssm_all = T.scan_layer_stack(

@@ -17,12 +17,23 @@ Span timestamps come from the engine's injectable clock (DESIGN §12), so
 a fake stepped clock yields byte-identical traces across runs.  All of
 this layer only *reads* engine state — token streams are byte-identical
 with telemetry on or off (asserted by tests/test_telemetry.py).
+
+Independently of any :class:`Telemetry`, every engine phase runs inside
+a :class:`Span`: a ``jax.profiler.TraceAnnotation`` named ``codec.*``,
+so a ``jax.profiler`` trace shows the engine's phases on the same clock
+as the device's operations.  The profiler alone turns them on.
+:class:`PrefillSpan` also counts the JAX program builds a prefill chunk
+triggers, through one process-wide ``jax.monitoring`` listener.
 """
 
 from __future__ import annotations
 
 import json
+import threading
+import time
 from typing import Any, Dict, List, Optional, Tuple
+
+import jax
 
 from ..core import metrics as metrics_mod
 
@@ -53,6 +64,11 @@ METRIC_CATALOG: Dict[str, Tuple[str, str]] = {
     "recompute_tokens": ("counter", "tokens recomputed after eviction"),
     "prefill_chunks": ("counter", "chunked prefill continuations"),
     "prefill_stalls": ("counter", "prefill chunks stalled on pages"),
+    "prefill_s": ("counter", "host seconds inside codec.prefill spans"),
+    "prefill_builds": ("counter",
+                       "JAX program builds (trace, lowering, compile or "
+                       "cache load) inside codec.prefill spans"),
+    "prefill_build_s": ("counter", "seconds of those program builds"),
     # cascade prefill (DESIGN §14)
     "cascade_groups": ("counter", "cascade group advances (>=2 members)"),
     "cascade_shared_tokens": ("counter",
@@ -128,6 +144,9 @@ ENGINE_STAT_COUNTERS: Dict[str, str] = {
     "recompute_tokens": "recompute_tokens",
     "prefill_chunks": "prefill_chunks",
     "prefill_stalls": "prefill_stalls",
+    "prefill_s": "prefill_s",
+    "prefill_builds": "prefill_builds",
+    "prefill_build_s": "prefill_build_s",
     "cascade_groups": "cascade_groups",
     "cascade_shared_tokens": "cascade_shared_tokens",
     "cascade_suffix_tokens": "cascade_suffix_tokens",
@@ -162,6 +181,131 @@ CACHE_STAT_COUNTERS: Dict[str, str] = {
     "evicted_nodes": "cache_evicted_nodes",
     "evicted_pages": "cache_evicted_pages",
 }
+
+
+# JAX's program-build events (jax/_src/dispatch.py): tracing to a jaxpr,
+# lowering to MLIR, and the backend compile, which also times a load from
+# the persistent compile cache
+BUILD_EVENTS = frozenset((
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration",
+))
+
+# per thread: the stats dict of the engine whose codec.prefill span is open
+_builds = threading.local()
+_listener_lock = threading.Lock()
+_listening = False
+
+
+def _on_build(event: str, secs: float, **_) -> None:
+    stats = getattr(_builds, "stats", None)
+    if stats is not None and event in BUILD_EVENTS:
+        stats["prefill_build_s"] += secs
+        stats["prefill_builds"] += 1
+
+
+def listen_for_builds() -> None:
+    """Register the process's one program-build listener (idempotent:
+    engines call it at construction)."""
+    global _listening
+    with _listener_lock:
+        if not _listening:
+            jax.monitoring.register_event_duration_secs_listener(_on_build)
+            _listening = True
+
+
+class Span:
+    """One engine phase on the profiler's clock (DESIGN §13).
+
+    ``with Span("codec.flush", tm, "flush") as sp:`` runs the block
+    inside ``jax.profiler.TraceAnnotation(name)`` (a
+    ``StepTraceAnnotation`` when ``step`` is given), which costs about
+    a microsecond unless a profiler trace is running, so nothing else
+    turns it on.  It takes two host readings, ``t0`` and ``t1``
+    (``time.perf_counter``; ``seconds`` is their difference), which
+    feed the engine's timing counters.  With a :class:`Telemetry`
+    ``tm`` and an ``event`` name, it also records that Chrome event on
+    ``track``, from ``c0``/``c1`` read from the telemetry's clock at
+    the same two points: the very same readings when that clock is
+    ``time.perf_counter``, the engine's default.  ``args`` (settable
+    inside the block) become the event's args.  A block that raises
+    records no event.
+    """
+
+    __slots__ = ("name", "tm", "event", "track", "args", "step",
+                 "t0", "t1", "c0", "c1", "_ann")
+
+    def __init__(self, name: str, tm: Optional["Telemetry"] = None,
+                 event: Optional[str] = None, track=None,
+                 args: Optional[Dict] = None,
+                 step: Optional[int] = None):
+        self.name, self.tm, self.event = name, tm, event
+        self.track = ENGINE if track is None else track
+        self.args, self.step = args, step
+        self.t0 = self.t1 = self.c0 = self.c1 = 0.0
+
+    def _clock(self, t: float) -> float:
+        clock = self.tm.clock
+        return t if clock is time.perf_counter else self.tm._now()
+
+    def __enter__(self) -> "Span":
+        self._ann = (jax.profiler.TraceAnnotation(self.name)
+                     if self.step is None else
+                     jax.profiler.StepTraceAnnotation(
+                         self.name, step_num=self.step))
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        if self.tm is not None and self.event is not None:
+            self.c0 = self._clock(self.t0)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.t1 = time.perf_counter()
+        try:
+            if (exc_type is None and self.tm is not None
+                    and self.event is not None):
+                self.c1 = self._clock(self.t1)
+                self.tm.complete(self.event, self.c0, self.c1,
+                                 track=self.track, args=self.args)
+        finally:
+            self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+
+class PrefillSpan(Span):
+    """``codec.prefill``: one prefill chunk, recorded as the request's
+    ``prefill_chunk`` event.  Adds its host seconds to
+    ``stats["prefill_s"]``; while it is open, the process's build
+    listener (:func:`listen_for_builds`) adds each program build's
+    seconds and count to ``stats["prefill_build_s"]`` and
+    ``stats["prefill_builds"]``.  A build may time builds nested in it
+    (a jitted function traced inside another), so the seconds are an
+    upper bound."""
+
+    __slots__ = ("stats", "_outer")
+
+    def __init__(self, stats: Dict[str, float],
+                 tm: Optional["Telemetry"] = None, track=None,
+                 args: Optional[Dict] = None):
+        super().__init__("codec.prefill", tm, "prefill_chunk", track, args)
+        self.stats = stats
+
+    def __enter__(self) -> "PrefillSpan":
+        self._outer = getattr(_builds, "stats", None)
+        _builds.stats = self.stats
+        return super().__enter__()
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        try:
+            return super().__exit__(exc_type, exc, tb)
+        finally:
+            _builds.stats = self._outer
+            self.stats["prefill_s"] += self.t1 - self.t0
 
 
 class TraceSink:

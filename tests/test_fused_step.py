@@ -120,7 +120,9 @@ def test_eager_step_stats_report_dispatch_and_compute():
     rows = [s for s in eng.step_stats if s.get("decoded")]
     assert rows and all("dispatch_time" in s and "compute_time" in s
                         for s in rows)
-    assert eng.stats["decode_time"] >= eng.stats["decode_dispatch_time"]
+    assert eng.stats["decode_dispatch_time"] > 0
+    assert eng.stats["decode_dispatch_time"] == pytest.approx(
+        sum(s["dispatch_time"] for s in rows))
 
 
 def test_fused_hybrid_mamba_matches_eager():

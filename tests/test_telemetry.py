@@ -343,3 +343,67 @@ def test_metrics_export_schema(tmp_path):
     assert set(doc["metrics"]) >= set(METRIC_CATALOG)
     for name, (kind, _) in METRIC_CATALOG.items():
         assert doc["metrics"][name]["type"] == kind
+
+
+# --------------------------------------------------------------------- #
+# profiler-clock spans and program-build counters
+# --------------------------------------------------------------------- #
+def test_span_records_its_event_from_its_readings():
+    import time
+
+    from repro.serving.telemetry import Span
+
+    # no Telemetry: the readings alone
+    with Span("codec.test") as sp:
+        pass
+    assert sp.t1 >= sp.t0 and sp.seconds == sp.t1 - sp.t0
+    # the engine's default clock: the event takes the very same readings
+    tm = Telemetry(clock=time.perf_counter)
+    with Span("codec.test", tm, "test", args={"a": 1}) as sp:
+        sp.args["b"] = 2
+    (ev,) = [e for e in tm.trace_events() if e["ph"] == "X"]
+    assert (sp.c0, sp.c1) == (sp.t0, sp.t1)
+    assert ev["name"] == "test" and ev["args"] == {"a": 1, "b": 2}
+    # an injected clock: the event rides it, the counters do not
+    clock = lambda: float(clock.t)         # noqa: E731
+    clock.t = 5.0
+    tm = Telemetry(clock=clock)
+    with Span("codec.test", tm, "test", step=3) as sp:
+        clock.t = 7.0
+    assert (sp.c0, sp.c1) == (5.0, 7.0)
+    assert sp.seconds < 1.0
+    # a block that raises records no event
+    with pytest.raises(RuntimeError):
+        with Span("codec.test", tm, "boom"):
+            raise RuntimeError
+    assert [e["name"] for e in tm.trace_events() if e["ph"] == "X"] == \
+        ["test"]
+
+
+def test_fused_step_lowering_carries_its_named_scopes():
+    eng = _engine(fused=True)
+    eng.add_request(DOC + [100], max_new=3)
+    eng.step()
+    text = eng.lower_fused_step().as_text(debug_info=True)
+    for scope in ("embed", "plan_advance", "kv_write", "pool_slice", "attn",
+                  "tail_page", "por_merge", "ffn", "sample"):
+        assert f"codec.{scope}" in text, scope
+
+
+def test_prefill_counts_its_program_builds():
+    """A cold prefill builds programs (the per-call ``lax.map`` in
+    ``layers.mha`` at least); the counters see them, and builds outside
+    a prefill span are not counted."""
+    tm = Telemetry()
+    eng = _engine(telemetry=tm)
+    eng.add_request(DOC + [100], max_new=2)
+    st = eng.stats
+    assert st["prefill_builds"] > 0
+    assert st["prefill_build_s"] > 0
+    assert st["prefill_s"] > 0
+    before = (st["prefill_builds"], st["prefill_build_s"])
+    jax.jit(lambda x: x * 3 + 1)(np.arange(7.0)).block_until_ready()
+    assert (st["prefill_builds"], st["prefill_build_s"]) == before
+    snap = eng.publish_metrics().snapshot()
+    assert snap["prefill_builds"]["value"] == st["prefill_builds"]
+    assert snap["prefill_s"]["value"] == pytest.approx(st["prefill_s"])
