@@ -49,9 +49,8 @@ PAGE = 16
 REQUESTS, DOC_LEN, QUESTION_LEN, MAX_NEW = 4, 1024, 32, 16
 MAX_Q = 8                      # query slots per plan task, as serve.py sets
 MESH = (2, 2)                  # (data, model) for --chips 4
-# device memory kept free of weights and KV pool: the compiled fused
-# step's temporaries and the eager prefill's (its full-vocabulary
-# logits over a 1056-token prompt alone are ~1 GB in f32)
+# device memory kept free of weights and KV pool: the temporaries of
+# the compiled fused step and prefill program
 HEADROOM_BYTES = 4 << 30
 # An emitted greedy token passes when its logit in the dense reference
 # forward is within TIE_ULPS bf16 ulps (at the maximum's magnitude) of

@@ -184,6 +184,20 @@ def layer_params(cfg: ModelConfig, params, j: int) -> Dict:
     return _period_slice(params["blocks"][f"sub{i}"], np.int32(pi))
 
 
+def _span_segments(path, start: int, end: int) -> List[Tuple[Any, int, int]]:
+    """``(node, lo, hi)`` for each node of ``path`` that the span
+    ``[start, end)`` covers, ``lo:hi`` in span-local coordinates (where
+    a chunk's KV lands and its SSM states are cached)."""
+    segments, off = [], 0
+    for node in path:
+        lo = max(0, off - start)
+        hi = min(end, off + node.length) - start
+        if hi > lo:
+            segments.append((node, lo, hi))
+        off += node.length
+    return segments
+
+
 class DecodeEngine:
     def __init__(self, cfg: ModelConfig, params, *,
                  page_size: int = 16, num_pages: int = 4096,
@@ -383,7 +397,8 @@ class DecodeEngine:
                       "decode_dispatch_time": 0.0,
                       "decode_sync_time": 0.0, "prefill_tokens": 0,
                       "prefill_s": 0.0, "prefill_build_s": 0.0,
-                      "prefill_builds": 0,
+                      "prefill_builds": 0, "prefill_jit_chunks": 0,
+                      "prefill_eager_chunks": 0, "prefill_signatures": 0,
                       "admitted": 0, "preempted": 0, "reclaimed": 0,
                       "recompute_tokens": 0, "prefill_chunks": 0,
                       "prefill_stalls": 0, "fused_calls": 0,
@@ -432,6 +447,17 @@ class DecodeEngine:
         elif self.fused:
             self._step_fn = step_fn_mod.make_step_fn(
                 cfg, self._backend, tuple(self._windows()), temperature)
+        # ---- prefill program (step_fn.make_prefill_fn) ---------------- #
+        # an attention-only stack on the single-device pool prefills each
+        # chunk as one jitted, donated dispatch per (T_pad, P_pad) shape
+        # bucket; recurrent (Mamba) mixers and the mesh pool keep the
+        # eager per-layer chunk (_prefill_eager)
+        self._prefill_fn = None
+        if mesh is None and all(k.mixer in ("attn", "attn_local")
+                                for k in self.layer_kinds):
+            self._prefill_fn = step_fn_mod.make_prefill_fn(cfg, temperature)
+        # distinct prefill program shapes seen (bounds its jit cache)
+        self.prefill_signatures: set = set()
         # epoch state: valid between plan rebuilds
         self._fused_rows: Optional[List[int]] = None
         self._fused_base: Optional[step_fn_mod.StepBase] = None
@@ -852,16 +878,8 @@ class DecodeEngine:
 
             paths = {req.rid: self.forest.path(req.rid)
                      for req, _, _ in batch}
-            segments: Dict[int, List[Tuple[Any, int, int]]] = {}
-            for req, start, end in batch:
-                segs, off = [], 0
-                for node in paths[req.rid]:
-                    lo = max(0, off - start)
-                    hi = min(end, off + node.length) - start
-                    if hi > lo:
-                        segs.append((node, lo, hi))
-                    off += node.length
-                segments[req.rid] = segs
+            segments = {req.rid: _span_segments(paths[req.rid], start, end)
+                        for req, start, end in batch}
 
             x = T._embed(self.params, cfg, jnp.asarray(tok), qpos_j)
             new_kv_writes = []   # (layer_attn, k (B_pad,T_pad,kv,hd), v)
@@ -908,24 +926,14 @@ class DecodeEngine:
 
             # write each row's new KV into its own nodes' unfilled slots
             with telemetry_mod.Span("codec.prefill.kv_write"):
-                ps = self.page_size
                 pages, offs, rows_b, rows_t = [], [], [], []
                 for i, (req, start, end) in enumerate(batch):
-                    for node, lo, hi in segments[req.rid]:
-                        filled = node.meta.get("filled", 0)
-                        base = node.start_pos - start
-                        t_hi = hi - base
-                        reps = node.meta.get("replicas")
-                        page_lists = (list(reps.values()) if reps
-                                      else [node.page_ids])
-                        for t in range(max(filled, lo - base), t_hi):
-                            for pl in page_lists:
-                                pages.append(pl[t // ps])
-                                offs.append(t % ps)
-                                rows_b.append(i)
-                                rows_t.append(base + t)
-                        if t_hi > filled:
-                            node.meta["filled"] = t_hi
+                    rows, pg, of = self._new_kv_slots(segments[req.rid],
+                                                      start)
+                    pages += pg
+                    offs += of
+                    rows_t += rows
+                    rows_b += [i] * len(rows)
                 if pages:
                     bi = jnp.asarray(np.asarray(rows_b))
                     ti = jnp.asarray(np.asarray(rows_t))
@@ -1725,26 +1733,131 @@ class DecodeEngine:
         """Compute positions ``[span_start, end)`` of the request's
         sequence: attention reads the cached prefix's KV, new KV lands in
         the unfilled page slots.  Samples ``pending`` when the sequence
-        completes; returns whether it did."""
+        completes; returns whether it did.  The prefill program
+        (``_prefill_program``) runs the chunk where the engine built one,
+        else the eager per-layer body (``_prefill_eager``)."""
+        total = len(req.seq)
+        segments = _span_segments(path, span_start, end)
+        # one key split per completing request with no surviving pending
+        # token, before either path runs: both sample with the same key
+        sk = None
+        if end >= total and req.pending is None:
+            self.key, sk = jax.random.split(self.key)
+
+        if self._prefill_fn is not None:
+            self.stats["prefill_jit_chunks"] += 1
+            out = self._prefill_program(req, path, segments, span_start,
+                                        end, sk)
+        else:
+            self.stats["prefill_eager_chunks"] += 1
+            out = self._prefill_eager(req, path, segments, span_start, end,
+                                      mamba_init)
+
+        Tn = end - span_start
+        self.stats["prefill_tokens"] += Tn
+        self.stats["recompute_tokens"] += max(
+            0, min(end, req.computed_hwm) - span_start)
+        req.computed_hwm = max(req.computed_hwm, end)
+        if end < total:
+            self.stats["prefill_chunks"] += 1
+            if self._mamba_layer_js:
+                self._mamba_pos[req.rid] = end
+            return False
+
+        if sk is not None:
+            with telemetry_mod.Span("codec.prefill.sample"):
+                if self._prefill_fn is not None:
+                    req.pending = int(out)
+                else:
+                    logits = T._unembed(self.params, self.cfg, out)[0, -1]
+                    req.pending = int(sampler.sample(logits[None], sk,
+                                                     self.temperature)[0])
+        return True
+
+    def _new_kv_slots(self, segments, span_start: int):
+        """Pool slots of the chunk's new KV: each covered node's unfilled
+        slots, in every replica's pages, which are then marked filled.
+        Returns span-local rows and their (page, offset) slots."""
+        ps = self.page_size
+        rows, pages, offs = [], [], []
+        for node, lo, hi in segments:
+            start = node.meta.get("filled", 0)
+            base = node.start_pos - span_start   # span-local token 0
+            t_hi = hi - base
+            # a replicated node's KV lands in EVERY shard's replica run
+            # (same source row scattered to each), keeping replicas
+            # bitwise in sync with the primary
+            reps = node.meta.get("replicas")
+            page_lists = list(reps.values()) if reps else [node.page_ids]
+            for t in range(max(start, lo - base), t_hi):
+                for pl in page_lists:
+                    pages.append(pl[t // ps])
+                    offs.append(t % ps)
+                    rows.append(base + t)
+            if t_hi > start:
+                node.meta["filled"] = t_hi
+        return rows, pages, offs
+
+    def _prefill_program(self, req: Request, path, segments,
+                         span_start: int, end: int, sk):
+        """One dispatch of the prefill program (``step_fn.
+        make_prefill_fn``) over the chunk, the pool donated.  The host
+        builds small index arrays only: the prefix's pages and each
+        slot's KV position (``codec.prefill.gather``), and the rows'
+        write slots (``codec.prefill.kv_write``).  Returns the sampled
+        token as a device scalar, read only when ``sk`` is given."""
+        ps = self.page_size
+        trash = self.pool.trash_page
+        Tn = end - span_start
+        T_pad = plan_mod.bucket_pow2(Tn)
+        with telemetry_mod.Span("codec.prefill.gather"):
+            pages, pos = [], []
+            upto = 0
+            for node in path:
+                take = min(node.length, span_start - upto)
+                if take <= 0:
+                    break
+                npg = -(-take // ps)
+                pages += node.page_ids[:npg]
+                slot = np.arange(npg * ps)
+                pos.append(np.where(slot < take, upto + slot,
+                                    step_fn_mod.NO_POS))
+                upto += take
+            P_pad = plan_mod.bucket_pow2(len(pages))
+            prefix_pages = np.full(P_pad, trash, np.int32)
+            prefix_pages[:len(pages)] = pages
+            prefix_pos = np.full(P_pad * ps, step_fn_mod.NO_POS, np.int32)
+            if pos:
+                pos = np.concatenate(pos)
+                prefix_pos[:len(pos)] = pos
+            tokens = np.zeros(T_pad, np.int32)
+            tokens[:Tn] = req.seq[span_start:end]
+            positions = np.full(T_pad, -1, np.int32)
+            positions[:Tn] = span_start + np.arange(Tn)
+        with telemetry_mod.Span("codec.prefill.kv_write"):
+            rows, w_pages, w_offs = self._new_kv_slots(segments, span_start)
+            write_page = np.full(T_pad, trash, np.int32)
+            write_off = np.zeros(T_pad, np.int32)
+            write_page[rows] = w_pages
+            write_off[rows] = w_offs
+        with telemetry_mod.Span("codec.prefill.attn"):
+            self.prefill_signatures.add((T_pad, P_pad))
+            self.stats["prefill_signatures"] = len(self.prefill_signatures)
+            tok, self.pool.k, self.pool.v = self._prefill_fn(
+                self.params, self.pool.k, self.pool.v, tokens, positions,
+                prefix_pages, prefix_pos, write_page, write_off,
+                np.int32(Tn - 1), self.key if sk is None else sk)
+        return tok
+
+    def _prefill_eager(self, req: Request, path, segments, span_start: int,
+                       end: int, mamba_init: Dict[int, Any]):
+        """The chunk op by op, one layer at a time (recurrent stacks and
+        the mesh pool).  Returns the last layer's hidden states."""
         cfg = self.cfg
         rid = req.rid
-        seq = req.seq
-        total = len(seq)
-        has_mamba = any(k.mixer == "mamba" for k in self.layer_kinds)
-
-        tokens = np.asarray(seq[span_start:end], np.int32)
+        tokens = np.asarray(req.seq[span_start:end], np.int32)
         Tn = len(tokens)
         positions = (span_start + np.arange(Tn))[None]           # (1, Tn)
-
-        # node segments covering the span (for KV writes + state caching)
-        segments = []        # (node, lo, hi) in span-local coordinates
-        off = 0
-        for node in path:
-            lo = max(0, off - span_start)
-            hi = min(end, off + node.length) - span_start
-            if hi > lo:
-                segments.append((node, lo, hi))
-            off += node.length
 
         x = T._embed(self.params, cfg, jnp.asarray(tokens)[None],
                      jnp.asarray(positions))
@@ -1795,49 +1908,14 @@ class DecodeEngine:
 
         # write new KV into unfilled page slots only
         with telemetry_mod.Span("codec.prefill.kv_write"):
-            offs, pages, kv_rows = [], [], []
-            ps = self.page_size
-            for node, lo, hi in segments:
-                start = node.meta.get("filled", 0)
-                base = node.start_pos - span_start   # span-local token 0
-                t_hi = hi - base
-                # a replicated node's KV lands in EVERY shard's replica
-                # run (same source row scattered to each), keeping
-                # replicas bitwise in sync with the primary
-                reps = node.meta.get("replicas")
-                page_lists = (list(reps.values()) if reps
-                              else [node.page_ids])
-                for t in range(max(start, lo - base), t_hi):
-                    for pl in page_lists:
-                        pages.append(pl[t // ps])
-                        offs.append(t % ps)
-                        kv_rows.append(base + t)
-                if t_hi > start:
-                    node.meta["filled"] = t_hi
+            kv_rows, pages, offs = self._new_kv_slots(segments, span_start)
             if kv_rows:
                 rows = jnp.asarray(np.asarray(kv_rows))
                 for la, k_new, v_new in new_kv_writes:
                     self.pool.write_tokens(la, np.asarray(pages),
                                            np.asarray(offs),
                                            k_new[rows], v_new[rows])
-
-        self.stats["prefill_tokens"] += Tn
-        self.stats["recompute_tokens"] += max(
-            0, min(end, req.computed_hwm) - span_start)
-        req.computed_hwm = max(req.computed_hwm, end)
-        if end < total:
-            self.stats["prefill_chunks"] += 1
-            if has_mamba:
-                self._mamba_pos[rid] = end
-            return False
-
-        if req.pending is None:
-            with telemetry_mod.Span("codec.prefill.sample"):
-                logits = T._unembed(self.params, cfg, x)[0, -1]
-                self.key, sk = jax.random.split(self.key)
-                req.pending = int(sampler.sample(logits[None], sk,
-                                                 self.temperature)[0])
-        return True
+        return x
 
     def _mamba_prefill(self, p, h, init):
         cfg = self.cfg

@@ -36,6 +36,9 @@ plan-rebuild and admission boundaries (see ``DecodeEngine.flush_tokens``).
 traced under ``shard_map`` over a ``(data, model)`` mesh, with
 per-shard plans and a cross-device POR merge (DESIGN.md §9); it reuses
 :class:`StepState` and the donation-warning shim from here.
+
+:func:`make_prefill_fn` builds prefill's counterpart: one prompt chunk
+as one jitted, donated program per power-of-two shape bucket.
 """
 
 from __future__ import annotations
@@ -45,6 +48,7 @@ from typing import Any, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..configs.base import ModelConfig
 from ..kernels import ops, ref as ref_mod
@@ -158,6 +162,86 @@ def make_spec_step_fn(cfg: ModelConfig, backend, windows: Tuple[int, ...]):
         return toks, ok, SpecState(pool_k, pool_v)
 
     return jax.jit(step, donate_argnums=(1,))
+
+
+# KV position of a slot the causal mask must drop: a page slot past its
+# node's cached length, a padded prefix page, a padded chunk row
+NO_POS = int(np.iinfo(np.int32).max)
+
+
+def make_prefill_fn(cfg: ModelConfig, temperature: float):
+    """Build the prefill program for one engine: one chunk of one
+    request's prompt in one jitted, donated dispatch.
+
+    Returns a jitted callable
+
+        ``fn(params, pool_k, pool_v, tokens, positions, prefix_pages,
+        prefix_pos, write_page, write_off, last, key)
+        -> (token, pool_k', pool_v')``
+
+    ``tokens``/``positions`` are the chunk's ``(T,)`` rows, padded rows
+    at position -1; ``prefix_pages`` the ``(P,)`` pool pages holding the
+    cached prefix and ``prefix_pos`` the ``(P * page,)`` KV position of
+    each of their slots (``NO_POS`` where the slot holds no prefix
+    token); ``write_page``/``write_off`` the pool slot each row's new
+    K/V lands in (the pool's trash page for rows whose KV is not
+    written); ``last`` the chunk's last real row, whose logits are
+    sampled with ``key``.
+
+    Per attention layer, inside ``transformer.scan_layer_stack``, the
+    body writes the rows' new K/V into the donated pool, gathers the
+    prefix pages back out of it (the written slots are never prefix
+    slots), and runs ``layers.mha`` over prefix + chunk exactly as the
+    eager chunk does: causal, the layer's window and softcap, the
+    prefix cast to the new K/V's dtype.  Every shape is a power-of-two
+    bucket chosen by the caller (``core.plan.bucket_pow2``), so the
+    programs number one per ``(T, P)`` bucket.  Attention stacks only:
+    recurrent mixers carry state the scan does not thread.
+    """
+    _silence_donation_warning()
+
+    def prefill(params, pool_k, pool_v, tokens, positions, prefix_pages,
+                prefix_pos, write_page, write_off, last, key):
+        T_pad = tokens.shape[0]
+        q_pos = positions[None]                             # (1, T)
+        kv_pos = jnp.concatenate(
+            [prefix_pos, jnp.where(positions >= 0, positions, NO_POS)])[None]
+        x = T._embed(params, cfg, tokens[None], q_pos)      # (1, T, d)
+
+        def body(c, kind, p, la, lm):
+            x, pool_k, pool_v = c
+            h = L.apply_norm(p["ln"], x, cfg)
+            w = cfg.sliding_window if kind.mixer == "attn_local" else 0
+            q, k_new, v_new = L.attn_project(p["attn"], cfg, h, q_pos)
+            with jax.named_scope("codec.prefill.kv_write"):
+                pool_k = pool_k.at[la, write_page, write_off].set(
+                    k_new[0].astype(pool_k.dtype))
+                pool_v = pool_v.at[la, write_page, write_off].set(
+                    v_new[0].astype(pool_v.dtype))
+            with jax.named_scope("codec.prefill.gather"):
+                shape = (1, -1) + k_new.shape[2:]
+                pk = pool_k[la, prefix_pages].reshape(shape)
+                pv = pool_v[la, prefix_pages].reshape(shape)
+                k_all = jnp.concatenate([pk.astype(k_new.dtype), k_new], 1)
+                v_all = jnp.concatenate([pv.astype(v_new.dtype), v_new], 1)
+            with jax.named_scope("codec.prefill.attn"):
+                o = L.mha(q, k_all, v_all, causal=True, window=w,
+                          softcap=cfg.attn_logit_softcap,
+                          q_positions=q_pos, kv_positions=kv_pos)
+            y = L.dense(p["attn"]["wo"],
+                        o.reshape(1, T_pad, cfg.num_heads * cfg.head_dim))
+            x, _ = L.apply_ffn_block(p, cfg, kind.ffn, x + y)
+            return (x, pool_k, pool_v)
+
+        x, pool_k, pool_v = T.scan_layer_stack(cfg, params, body,
+                                               (x, pool_k, pool_v))
+        with jax.named_scope("codec.prefill.sample"):
+            x_last = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
+            logits = T._unembed(params, cfg, x_last)[:, 0]  # (1, V)
+            tok = sampler.sample(logits, key, temperature)[0]
+        return tok, pool_k, pool_v
+
+    return jax.jit(prefill, donate_argnums=(1, 2))
 
 
 def make_step_fn(cfg: ModelConfig, backend, windows: Tuple[int, ...],

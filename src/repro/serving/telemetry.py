@@ -69,6 +69,13 @@ METRIC_CATALOG: Dict[str, Tuple[str, str]] = {
                        "JAX program builds (trace, lowering, compile or "
                        "cache load) inside codec.prefill spans"),
     "prefill_build_s": ("counter", "seconds of those program builds"),
+    "prefill_jit_chunks": ("counter",
+                           "prefill chunks run by the prefill program"),
+    "prefill_eager_chunks": ("counter",
+                             "prefill chunks run op by op (recurrent "
+                             "stacks, mesh pool)"),
+    "prefill_signatures": ("counter",
+                           "distinct (T_pad, P_pad) prefill program shapes"),
     # cascade prefill (DESIGN §14)
     "cascade_groups": ("counter", "cascade group advances (>=2 members)"),
     "cascade_shared_tokens": ("counter",
@@ -147,6 +154,9 @@ ENGINE_STAT_COUNTERS: Dict[str, str] = {
     "prefill_s": "prefill_s",
     "prefill_builds": "prefill_builds",
     "prefill_build_s": "prefill_build_s",
+    "prefill_jit_chunks": "prefill_jit_chunks",
+    "prefill_eager_chunks": "prefill_eager_chunks",
+    "prefill_signatures": "prefill_signatures",
     "cascade_groups": "cascade_groups",
     "cascade_shared_tokens": "cascade_shared_tokens",
     "cascade_suffix_tokens": "cascade_suffix_tokens",
