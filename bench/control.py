@@ -36,7 +36,7 @@ def readings(cell_name: str, seed: int, seconds: float, control: bool):
     limit = spec.limits(cell["name"])["max_logit_gap"]["limit"]
     dev = find_chips(cell["chips"])[0]
     params = weights.make(model, seed)
-    pages = harness.pool_pages(model, dev.memory_stats()["bytes_limit"],
+    pages = harness.pool_pages(model, mix, dev.memory_stats()["bytes_limit"],
                                weights.nbytes(params))
     traffic = ClosedLoopTraffic(mix, seed, model["vocab_size"])
     eng = harness.build_engine(model, mix, params, pages)

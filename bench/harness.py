@@ -2,8 +2,9 @@
 
 Everything here runs the program as a deployment would: the engine is
 built as ``launch/serve.py`` builds it, from the settings in the cell's
-configuration file, and the loop calls only ``add_request`` and
-``step``.  The harness keeps its own clock, counts and spans."""
+configuration file and its architecture module, and the loop calls only
+``add_request`` and ``step``.  The harness keeps its own clock, counts
+and spans."""
 
 from __future__ import annotations
 
@@ -13,7 +14,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 
-from . import work
+from . import spec, work
 
 now = time.perf_counter
 
@@ -43,39 +44,29 @@ class CompileClock:
         return self.seconds, self.compiles, self.cache_hits
 
 
-def model_config(model: Dict[str, Any]):
-    """The engine's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import LayerKind, ModelConfig
-
-    if model["hidden_act"] != "silu":
-        raise ValueError("only SwiGLU MLPs are mapped")
-    return ModelConfig(
-        name=model["name"], family="dense",
-        num_layers=model["num_hidden_layers"], d_model=model["hidden_size"],
-        num_heads=model["num_attention_heads"],
-        num_kv_heads=model["num_key_value_heads"],
-        head_dim=model["head_dim"], d_ff=model["intermediate_size"],
-        vocab_size=model["vocab_size"],
-        qkv_bias=bool(model["attention_bias"]),
-        rope_theta=float(model["rope_theta"]),
-        norm_eps=float(model["rms_norm_eps"]),
-        layer_pattern=(LayerKind("attn", "mlp"),),
-        tie_embeddings=bool(model["tie_word_embeddings"]),
-        dtype=model["weights"]["dtype"])
-
-
 def page_bytes(model: Dict[str, Any]) -> int:
     """Bytes of one KV page: K and V over every attention layer."""
+    arch = spec.architecture_of(model)
     e = model["engine"]
+    heads = arch.attention_heads(model)
     width = jax.numpy.dtype(e["kv_pool_dtype"]).itemsize
-    return (2 * model["num_hidden_layers"] * e["page_size"]
-            * model["num_key_value_heads"] * model["head_dim"] * width)
+    return (2 * arch.attention_layers(model) * e["page_size"]
+            * heads["hkv"] * heads["hd"] * width)
 
 
-def pool_pages(model: Dict[str, Any], bytes_limit: int,
-               weight_bytes: int) -> int:
-    """Pages that fit beside the weights, less the stated headroom."""
-    free = bytes_limit - weight_bytes - model["engine"]["headroom_bytes"]
+def max_rows(model: Dict[str, Any], mix: Dict[str, Any]) -> int:
+    """Rows the engine's decode step holds (its ``max_q``)."""
+    return max(int(mix["clients"]), model["engine"]["min_max_q"])
+
+
+def pool_pages(model: Dict[str, Any], mix: Dict[str, Any],
+               bytes_limit: int, weight_bytes: int) -> int:
+    """Pages that fit beside the weights and the recurrent state of the
+    engine's rows, less the stated headroom."""
+    state = spec.architecture_of(model).state_bytes(model,
+                                                    max_rows(model, mix))
+    free = (bytes_limit - weight_bytes - state
+            - model["engine"]["headroom_bytes"])
     return max(0, free // page_bytes(model))
 
 
@@ -88,9 +79,9 @@ def build_engine(model: Dict[str, Any], mix: Dict[str, Any], params,
     if e["prefix_cache"] != "CachePolicy()":
         raise ValueError("the harness maps only the default CachePolicy")
     return DecodeEngine(
-        model_config(model), params, page_size=e["page_size"],
-        num_pages=num_pages, backend=e["backend"],
-        max_q=max(int(mix["clients"]), e["min_max_q"]),
+        spec.architecture_of(model).model_config(model), params,
+        page_size=e["page_size"], num_pages=num_pages, backend=e["backend"],
+        max_q=max_rows(model, mix),
         temperature=e["temperature"], fused=e["fused"],
         cache=CachePolicy())
 
@@ -243,17 +234,19 @@ def trace_steps(loop: ClosedLoop, t0: float, t1: float):
     return [s for s in loop.steps if t0 <= s[0] < t1 and s[1]]
 
 
-def step_work(model_dims, steps, shared: int, page: int, kv_bytes: int,
-              tied: bool, peak: Dict[str, float]) -> Dict[str, Any]:
+def step_work(model: Dict[str, Any], steps, shared: int, page: int,
+              kv_bytes: int, peak: Dict[str, float]) -> Dict[str, Any]:
     """Over the given step records: the mean model FLOPs of one step,
     and the mean least time of one attention layer's kernel call."""
     if not steps:
         return {}
+    arch = spec.architecture_of(model)
+    heads = arch.attention_heads(model)
     f = least = 0.0
     bound: Dict[str, int] = {}
     for _, ctx, priv in steps:
-        f += work.step_flops(model_dims, ctx, tied)
-        fl, by = work.attention_work(model_dims, work.two_level(shared, priv),
+        f += arch.step_flops(model, ctx)
+        fl, by = work.attention_work(heads, work.two_level(shared, priv),
                                      len(ctx), page, kv_bytes)
         t, which = work.least_time(fl, by, peak)
         least += t

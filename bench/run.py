@@ -118,6 +118,7 @@ def run(args) -> dict:
     limit = spec.limits(cell["name"])["max_logit_gap"]["limit"]
     names = spec.kernels(model["engine"]["backend"])
     ref = spec.reference(model["reference"])
+    arch = spec.architecture_of(model)
 
     use_compile_cache()
     import jax
@@ -137,13 +138,13 @@ def run(args) -> dict:
             f"compiled, {hits} loaded, {sec:.1f} s)")
         t_phase[0] = t
 
-    mcfg = harness.model_config(model)
+    mcfg = arch.model_config(model)
     params = weights.make(model, args.seed)
     weights.check(params, jax.eval_shape(
         lambda k: T.init_params(mcfg, k), jax.random.PRNGKey(0)))
     wbytes = weights.nbytes(params)
     page = model["engine"]["page_size"]
-    pages = harness.pool_pages(model, dev.memory_stats()["bytes_limit"],
+    pages = harness.pool_pages(model, mix, dev.memory_stats()["bytes_limit"],
                                wbytes)
     traffic = ClosedLoopTraffic(mix, args.seed, model["vocab_size"])
     need = traffic.worst_case_pages(page)
@@ -227,16 +228,14 @@ def run(args) -> dict:
         summ = trace.summarize(events, names["step_program"],
                                names["attention_ops"], window)
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
-        dims = weights.dims(model)
         steps = harness.trace_steps(loop, *traced)
         ctx = {
-            "trace": summ, "peak": peak, "dims": dims,
+            "trace": summ, "peak": peak, "dims": arch.dims(model),
             "prompt_tokens": sum(len(r.prompt) for r in sent_in_window),
             "prefill_tokens": pf1 - pf0,
             "admit_s": [r.admit_s for r in sent_in_window],
             "work": harness.step_work(
-                dims, steps, len(traffic.shared), page, kv_bytes,
-                bool(model["tie_word_embeddings"]), peak),
+                model, steps, len(traffic.shared), page, kv_bytes, peak),
         }
         result["metrics"] = per_layer(bench, cell["name"], ctx)
         device.update(busy_s=summ["busy_s"], window_s=summ["window_s"])

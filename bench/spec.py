@@ -1,16 +1,23 @@
 """Find a cell's pieces by name: ``BENCHMARK.json`` at the repository
-root, and the configuration, traffic, peak and limit files under
-``bench/``."""
+root, and the configuration, architecture, traffic, peak and limit files
+under ``bench/``."""
 
 from __future__ import annotations
 
 import importlib.util
 import json
 import os
+import re
 from typing import Any, Callable, Dict, List, Optional
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
+ARCHITECTURES = os.path.join(BENCH_DIR, "architectures")
+_NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
+# what every architecture module gives (see ``architecture``)
+ARCHITECTURE_API = ("model_config", "build", "dims", "attention_layers",
+                    "attention_heads", "state_bytes", "step_flops",
+                    "smoke", "control_smoke")
 
 
 def _load(path: str) -> Dict[str, Any]:
@@ -76,6 +83,49 @@ def reference(name: str):
     """A configuration's plain reference module, named in its file."""
     path = os.path.join(BENCH_DIR, "references", f"{name}.py")
     return _module(path, f"reference:{name}")
+
+
+def architecture(name: str):
+    """An architecture module (``bench/architectures/<name>.py``): all
+    that the harness knows of a model's shape.  An unknown name is an
+    error, never a default.  A module gives, each from the
+    configuration's dict ``model``:
+
+    - ``model_config(model)``: the engine's ``ModelConfig``;
+    - ``build(model, key)``: weights from a JAX key, in the engine's
+      parameter layout (``weights.make`` runs it under one jit);
+    - ``dims(model)``: the sizes a per-layer reader may read;
+    - ``attention_layers(model)``: layers whose K and V a pool page holds;
+    - ``attention_heads(model)``: ``{"hq", "hkv", "hd"}`` of one
+      attention kernel call;
+    - ``state_bytes(model, rows)``: device bytes the engine holds beside
+      the KV pool for ``rows`` live rows (recurrent state);
+    - ``step_flops(model, contexts)``: model FLOPs of one decode step
+      over live rows with these context lengths;
+    - ``smoke(model)`` and ``control_smoke(model)``: the configuration
+      at the CPU sizes of the tests' rehearsal and float8 control.
+    """
+    path = os.path.join(ARCHITECTURES, f"{name}.py")
+    if not _NAME.fullmatch(name) or not os.path.isfile(path):
+        known = sorted(f[:-3] for f in os.listdir(ARCHITECTURES)
+                       if f.endswith(".py"))
+        raise KeyError(f"no architecture {name!r} in {ARCHITECTURES} "
+                       f"(known: {known})")
+    mod = _module(path, f"architecture:{name}")
+    missing = [f for f in ARCHITECTURE_API if not callable(getattr(mod, f,
+                                                                   None))]
+    if missing:
+        raise KeyError(f"architecture {name!r} lacks {missing}")
+    return mod
+
+
+def architecture_of(model: Dict[str, Any]):
+    """The architecture module a configuration names; a configuration
+    that names none is refused."""
+    if "architecture" not in model:
+        raise KeyError(f"configuration {model.get('name')!r} names no "
+                       f"\"architecture\"")
+    return architecture(model["architecture"])
 
 
 def kernels(backend: str) -> Dict[str, Any]:

@@ -6,11 +6,12 @@ They cover the trace reduction (on an excerpt recorded on a v5e), the
 FLOP and byte counters against hand counts, the traffic generator, the
 entry's refusal to measure without a TPU, a rehearsal of every cell at
 smoke size through the harness's own functions (which also checks that
-the fused step is still the program the trace reduction looks for), the
-correctness check failing when a served token is altered where it is
-produced, the float8 control failing the smoke check, and a compile of
-PAC for a described v5e at qwen2.5-14b's heads (which checks the
-kernel's trace name).
+the fused step is still the program the trace reduction looks for; each
+configuration at its architecture module's smoke size), the correctness
+check failing when a served token is altered where it is produced, the
+float8 control failing the smoke check, and a compile of PAC for a
+described v5e at qwen2.5-14b's heads (which checks the kernel's trace
+name).
 """
 
 from __future__ import annotations
@@ -41,14 +42,9 @@ CELLS = [(w["name"], w["config"], w["traffic"])
 
 # ---------------------------------------------------------- smoke sizes
 def smoke_model(config):
-    """A configuration at smoke widths: the same engine settings,
-    weights recipe and reference."""
-    m = copy.deepcopy(config)
-    m.update(hidden_size=64, intermediate_size=96, num_hidden_layers=2,
-             num_attention_heads=4, num_key_value_heads=2, head_dim=16,
-             vocab_size=257)
-    m["engine"]["headroom_bytes"] = 0
-    return m
+    """A configuration at its architecture's smoke widths: the same
+    engine settings, weights recipe and reference."""
+    return spec.architecture_of(config).smoke(config)
 
 
 def smoke_mix(mix):
@@ -116,15 +112,19 @@ def test_reduction_of_recorded_v5e_trace():
 
 
 # ------------------------------------------------------------- counters
-N = {"layers": 2, "d": 8, "hq": 4, "hkv": 2, "hd": 4, "ff": 16, "vocab": 10}
+HEADS = {"hq": 4, "hkv": 2, "hd": 4}
 
 
 def test_step_flops_by_hand():
-    # per token: layers * (8*(4+4)*4 + 4*4*8 + 3*8*16) + 8*10
-    per_tok = 2 * (256 + 128 + 384) + 80
-    assert work.matmul_params_per_token(N, True) == per_tok
-    # two rows with contexts 5 and 7: 2*per_tok*2 + 4*4*4*12*2
-    assert work.step_flops(N, [5, 7]) == 2 * per_tok * 2 + 4 * 4 * 4 * 12 * 2
+    """The dense architecture at its smoke widths: 2 layers of width
+    64, 4 query and 2 KV heads of 16, MLP 96, vocabulary 257."""
+    arch = spec.architecture("dense_gqa")
+    m = arch.smoke(spec.config("qwen3-4b"))
+    # per token: layers * (64*(4+4)*16 + 4*16*64 + 3*64*96) + 64*257
+    per_tok = 2 * (8192 + 4096 + 18432) + 16448
+    assert arch.matmul_params_per_token(m) == per_tok
+    # two rows with contexts 5 and 7: 2*per_tok*2 + 4*4*16*12*2
+    assert arch.step_flops(m, [5, 7]) == 2 * per_tok * 2 + 4 * 4 * 16 * 12 * 2
 
 
 def test_two_level_forest_pages_and_bytes_by_hand():
@@ -134,14 +134,14 @@ def test_two_level_forest_pages_and_bytes_by_hand():
     pages, row_tokens = work.forest_pages(nodes, 16)
     assert pages == 4 + 1 + 1 + 0                 # partial pages are tails
     assert row_tokens == 64 * 3 + 16 + 16
-    flops, nbytes = work.attention_work(N, nodes, rows=3, page=16,
+    flops, nbytes = work.attention_work(HEADS, nodes, rows=3, page=16,
                                         kv_bytes=4)
     assert flops == 4 * 4 * 4 * (64 * 3 + 32)
     kv = 2 * 6 * 16 * 2 * 4 * 4                   # K and V, 6 pages, f32
     qo = 3 * 4 * (4 * 2 + (4 + 2) * 4)
     assert nbytes == kv + qo
     # per-request reads would be 3x the shared pages: sharing is counted
-    _, unshared = work.attention_work(N, [(84, 1), (80, 1), (69, 1)],
+    _, unshared = work.attention_work(HEADS, [(84, 1), (80, 1), (69, 1)],
                                       rows=3, page=16, kv_bytes=4)
     assert unshared > nbytes
 
@@ -193,21 +193,28 @@ def test_priming_covers_the_shared_prefix_in_chunks():
                              1000).prime_prompts(0) == []
 
 
+def weight_bytes(model):
+    """Bytes of the weights the architecture module draws, from shapes
+    alone (nothing is drawn)."""
+    import jax
+    tree = jax.eval_shape(
+        lambda k: spec.architecture_of(model).build(model, k),
+        jax.random.PRNGKey(0))
+    return sum(int(np.prod(x.shape)) * x.dtype.itemsize
+               for x in jax.tree.leaves(tree))
+
+
 def test_pool_holds_each_cell_worst_case():
-    """At v5e's bytes_limit the stated headroom leaves each cell's pool
-    at least the pages its mix can hold at once (no preemption)."""
+    """At v5e's bytes_limit the weights, the stated headroom and the
+    recurrent state leave each cell's pool at least the pages its mix
+    can hold at once (no preemption)."""
     limit = 16_909_336_576          # bytes_limit of one v5e, 16 GiB HBM
     for _, cfg, mix in CELLS:
-        m = spec.config(cfg)
-        n = weights.dims(m)
-        params = (n["vocab"] * n["d"] * (1 if m["tie_word_embeddings"]
-                                          else 2)
-                  + n["layers"] * (
-                      work.matmul_params_per_token(n, True)
-                      - n["d"] * n["vocab"]) // n["layers"])
-        t = ClosedLoopTraffic(spec.traffic(mix), 0, n["vocab"])
+        m, x = spec.config(cfg), spec.traffic(mix)
+        t = ClosedLoopTraffic(x, 0, m["vocab_size"])
         need = t.worst_case_pages(m["engine"]["page_size"])
-        assert harness.pool_pages(m, limit, 2 * params) >= need, (cfg, mix)
+        assert harness.pool_pages(m, x, limit, weight_bytes(m)) >= need, (
+            cfg, mix)
 
 
 # ------------------------------------------------------------ entry
@@ -406,8 +413,8 @@ def test_pac_compiles_for_v5e_at_qwen2_5_14b_heads(one_chip,
 
     m = spec.config("qwen2.5-14b-s12")
     rows, page = 16, m["engine"]["page_size"]
-    hq, hkv, hd = (m["num_attention_heads"], m["num_key_value_heads"],
-                   m["head_dim"])
+    heads = spec.architecture_of(m).attention_heads(m)
+    hq, hkv, hd = heads["hq"], heads["hkv"], heads["hd"]
     forest = tree_mod.two_level(rows, 4096, 384, block_size=page)
     num_pages = plan_mod.assign_dense_pages(forest) + 8
     cm = cost_model.CostModel(hq, hkv, hd, page_size=page)
@@ -434,14 +441,12 @@ def test_pac_compiles_for_v5e_at_qwen2_5_14b_heads(one_chip,
 def test_float8_control_fails_the_cell_limit(cell):
     """The control -- the plain reference computed with float8 operands
     in the program's place -- reads a widest gap above the cell's limit
-    on a model small enough for the CPU (vocabulary 32768, width 512,
-    4 layers, the cell's weights recipe), while the float32 reference's
+    on a model small enough for the CPU (the architecture module's
+    ``control_smoke``: for dense_gqa vocabulary 32768, width 512, 4
+    layers; the cell's weights recipe), while the float32 reference's
     own greedy tokens read 0."""
-    m = smoke_model(spec.config(
-        spec.workload(spec.benchmark(), cell)["config"]))
-    m.update(vocab_size=32768, hidden_size=512, intermediate_size=1024,
-             num_hidden_layers=4, num_attention_heads=4,
-             num_key_value_heads=2, head_dim=64)
+    config = spec.config(spec.workload(spec.benchmark(), cell)["config"])
+    m = spec.architecture_of(config).control_smoke(config)
     ref = spec.reference(m["reference"])
     params = weights.make(m, 11)
     rng = np.random.default_rng(0)
